@@ -1,8 +1,8 @@
 """Fed-mesh scaling: 10^5-client frontier + clients-vs-wall-clock ladder.
 
-The ISSUE deliverable for the mesh-sharded federated runtime
-(``repro.fed.mesh``, guide: docs/fed_scaling.md): run a federated sweep
-with >= 10^5 clients on 8 XLA host devices and report
+The mesh-sharded federated runtime (``repro.fed.mesh``, guide:
+docs/fed_scaling.md) runs a federated sweep with >= 10^5 clients over the
+devices of one host and reports
 
   * a **scenario frontier** — bytes / energy / wall-clock / accuracy for
     a small grid of deployment scenarios (participation, uplink loss,
@@ -13,10 +13,10 @@ with >= 10^5 clients on 8 XLA host devices and report
     scale" story (``collect_mask=False``, ``bake_data=False`` — the
     documented 10^6-client knobs; masks and counts are unchanged).
 
-The benchmark driver's process is pinned to one device (XLA reads
-``XLA_FLAGS`` at first backend init), so the measured body runs in a
-subprocess with ``--xla_force_host_platform_device_count=8`` — the same
-harness ``tests/test_distributed.py`` uses for the mesh exactness pins.
+The body runs in the calling process over every device JAX finds, so on
+a TPU host it holds the chips itself. A CPU rehearsal gets its 8 host
+devices from the command line:
+``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
 Numbers land in ``BENCH_fed_mesh.json``; CI runs the fast shapes and
 gates against the committed ``BENCH_fed_mesh_smoke.json`` baseline via
@@ -24,49 +24,34 @@ gates against the committed ``BENCH_fed_mesh_smoke.json`` baseline via
 gate: the ideal scenario must converge to f*, censoring must save bytes
 versus transmit-everything, and every ladder rung must complete.
 """
-import json
 import os
-import subprocess
-import sys
-import textwrap
+import time
+
+import jax
+import numpy as np
+
+from repro import fed, opt
+from repro.data import edge_tasks
+from repro.launch import mesh as mk
 
 # REPRO_BENCH_FAST=1: CI-smoke shapes — same code paths, tiny population
 FAST = os.environ.get("REPRO_BENCH_FAST", "") not in ("", "0")
 
-DEVICES = 8
 FRONTIER_M = 800 if FAST else 100_000
 FRONTIER_ROUNDS = 60 if FAST else 80
 LADDER_M = (400, 800, 1600) if FAST else (100_000, 250_000, 500_000,
                                           1_000_000)
 LADDER_ROUNDS = 3 if FAST else 5
 
-_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SCENARIOS = (("ideal", 1.0, 0.0, 1.0),
+             ("lossy", 1.0, 0.2, 0.7),
+             ("partial", 0.5, 0.0, 0.5),
+             ("harsh", 0.5, 0.3, 0.5))
 
-# The measured body. Runs on 8 host devices in a fresh process; prints
-# exactly one JSON line on the last stdout line (everything else it may
-# print is progress noise the parent ignores).
-_SUB = textwrap.dedent("""
-    import json
-    import time
 
-    import jax
-
-    jax.config.update("jax_enable_x64", True)
-
-    import numpy as np
-
-    from repro import fed, opt
-    from repro.data import edge_tasks
-    from repro.launch import mesh as mk
-
-    CFG = json.loads({cfg!r})
-    K = CFG["devices"]
-    assert jax.device_count() == K, (jax.device_count(), K)
-    mesh = mk.make_client_mesh(K)
-
+def _measure(mesh) -> dict:
     # ---- scenario frontier at FRONTIER_M clients ----------------------
-    M = CFG["frontier_m"]
-    R = CFG["frontier_rounds"]
+    M, R = FRONTIER_M, FRONTIER_ROUNDS
     task = edge_tasks.make_edge_quadratics(M, d=16, seed=0)
     fstar = edge_tasks.edge_quadratics_fstar(task)
     # 0.5/M keeps alpha * L ~ mean(a)/2 < 1 at any M (curvatures are
@@ -81,10 +66,6 @@ _SUB = textwrap.dedent("""
     en = fed.EnergyModel()
     payload = o.transport.payload_bytes(task.init_params)
 
-    SCENARIOS = (("ideal", 1.0, 0.0, 1.0),
-                 ("lossy", 1.0, 0.2, 0.7),
-                 ("partial", 0.5, 0.0, 0.5),
-                 ("harsh", 0.5, 0.3, 0.5))
     frontier = []
     for name, part, loss, quo in SCENARIOS:
         sc = fed.MeshScenario(participation=part, loss_prob=loss,
@@ -107,50 +88,32 @@ _SUB = textwrap.dedent("""
             gap=float(mh.objective[-1] - fstar)))
 
     # ---- clients-vs-wall-clock ladder ---------------------------------
-    LR = CFG["ladder_rounds"]
     ladder = []
-    for m in CFG["ladder_m"]:
+    for m in LADDER_M:
         t = edge_tasks.make_edge_quadratics(m, d=16, seed=0)
         ol = opt.make("chb", 0.5 / m, m, eps1=4.0)
         t0 = time.perf_counter()
-        mh = fed.run_mesh(ol, t, LR, mesh=mesh,
+        mh = fed.run_mesh(ol, t, LADDER_ROUNDS, mesh=mesh,
                           scenario=fed.MeshScenario(seed=0),
                           collect_mask=False, bake_data=False)
         total = time.perf_counter() - t0
         assert np.isfinite(mh.objective).all()
-        ladder.append(dict(clients=m, rounds=LR,
+        ladder.append(dict(clients=m, rounds=LADDER_ROUNDS,
                            total_s=round(total, 2),
-                           s_per_round=round(total / LR, 3),
-                           client_rounds_per_s=round(m * LR / total)))
-
-    print(json.dumps(dict(frontier=frontier, ladder=ladder,
-                          payload_bytes=payload, fstar=fstar,
-                          devices=K)))
-""")
-
-
-def _run_sub(cfg: dict) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (f"--xla_force_host_platform_device_count="
-                        f"{cfg['devices']}")
-    env["PYTHONPATH"] = os.path.join(_ROOT, "src")
-    code = _SUB.format(cfg=json.dumps(cfg))
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, env=env, timeout=3600)
-    if r.returncode != 0:
-        raise RuntimeError("fed_mesh subprocess failed:\n"
-                           + r.stdout[-2000:] + r.stderr[-2000:])
-    return json.loads(r.stdout.splitlines()[-1])
+                           s_per_round=round(total / LADDER_ROUNDS, 3),
+                           client_rounds_per_s=round(
+                               m * LADDER_ROUNDS / total)))
+    return dict(frontier=frontier, ladder=ladder, payload_bytes=payload,
+                fstar=fstar)
 
 
 def main() -> tuple[str, dict]:
-    cfg = dict(devices=DEVICES, frontier_m=FRONTIER_M,
-               frontier_rounds=FRONTIER_ROUNDS,
-               ladder_m=list(LADDER_M), ladder_rounds=LADDER_ROUNDS)
-    out = _run_sub(cfg)
+    jax.config.update("jax_enable_x64", True)
+    devices = jax.device_count()
+    out = _measure(mk.make_client_mesh(devices))
     frontier, ladder = out["frontier"], out["ladder"]
 
-    print(f"fed_mesh: {DEVICES} host devices, frontier at "
+    print(f"fed_mesh: {devices} {jax.default_backend()} devices, frontier at "
           f"{FRONTIER_M:,} clients, ladder to {LADDER_M[-1]:,}")
     print(f"{'scenario':>9} {'part':>5} {'loss':>5} {'quo':>4} "
           f"{'MBytes':>9} {'kJ':>8} {'sim_s':>8} {'gap/gap0':>9}")
@@ -184,10 +147,10 @@ def main() -> tuple[str, dict]:
 
     us = ladder[-1]["s_per_round"] * 1e6
     row = (f"fed_mesh,{us:.1f},"
-           f"clients_max={LADDER_M[-1]};devices={DEVICES};"
+           f"clients_max={LADDER_M[-1]};devices={devices};"
            f"ideal_relgap={ideal['gap'] / ideal['gap0']:.2e}")
-    payload = dict(row=row, backend="cpu", fast=FAST,
-                   devices=DEVICES, payload_bytes=out["payload_bytes"],
+    payload = dict(row=row, backend=jax.default_backend(), fast=FAST,
+                   devices=devices, payload_bytes=out["payload_bytes"],
                    fstar=out["fstar"], frontier=frontier, ladder=ladder,
                    spec=None)
     return row, payload

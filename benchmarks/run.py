@@ -69,6 +69,8 @@ def main() -> None:
     # this from eagerly importing common — keep it explicit under lazy import
     import jax
     jax.config.update("jax_enable_x64", True)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     names = [args.only] if args.only else list(BENCH_NAMES)
     rows, payloads, failed = [], {}, []

@@ -73,40 +73,6 @@ def _payload_bytes(cfg, params) -> int:
     return payload_bytes_dense(params)
 
 
-def _shard_map(f, mesh, in_specs, out_specs, manual_axes):
-    """shard_map across jax versions, split by manual-axis coverage.
-
-    Full-manual (``manual_axes`` covers every mesh axis) works everywhere:
-    on jax >= 0.5 via the top-level ``jax.shard_map``, on the pinned 0.4.x
-    via ``jax.experimental.shard_map.shard_map`` with ``check_rep=False``
-    (its replication checker predates several collectives we use; the
-    out_specs still enforce the layout). This is the path the client-mesh
-    fold (``make_client_fold``) takes.
-
-    Partial-manual (some axes left auto, e.g. the pod strategy's manual
-    "pod" over an auto data/model submesh) needs jax >= 0.5: the 0.4.x
-    experimental ``shard_map(auto=...)`` hard-crashes the XLA SPMD
-    partitioner for this program (process abort, no traceback — HLO repro
-    preserved in launch/hlo_analysis.py's module docstring), so fail fast.
-    """
-    manual_axes = set(manual_axes)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs,
-                             axis_names=manual_axes, check_vma=False)
-    if manual_axes == set(mesh.axis_names):
-        from jax.experimental.shard_map import shard_map as _exp_shard_map
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-    raise NotImplementedError(
-        "partial-manual shard_map (manual "
-        f"{sorted(manual_axes)} over auto "
-        f"{sorted(set(mesh.axis_names) - manual_axes)}) needs the "
-        "top-level jax.shard_map API (jax >= 0.5); the 0.4.x experimental "
-        "shard_map trips an XLA SPMD-partitioner CHECK in partial-manual "
-        "mode")
-
-
 def make_client_fold(mesh, axis: str = "clients"):
     """Build the server-side quorum fold for a client mesh.
 
@@ -128,8 +94,9 @@ def make_client_fold(mesh, axis: str = "clients"):
         return jax.tree_util.tree_map(
             lambda v: jax.lax.psum(v[0], axis), stacked)
 
-    return _shard_map(fold_local, mesh, in_specs=(_P(axis),),
-                      out_specs=_P(), manual_axes={axis})
+    # check_vma=False: the out_specs still enforce the layout
+    return jax.shard_map(fold_local, mesh=mesh, in_specs=(_P(axis),),
+                         out_specs=_P(), axis_names={axis}, check_vma=False)
 
 
 # ============================================================ scan strategy
@@ -310,8 +277,10 @@ def make_pod_step(cfg,
                 P("pod") if cfg.quantize else P(), P("pod"))
     out_specs = (pspec, pspec, P("pod"),
                  P("pod") if cfg.quantize else P(), P(), P(), P(), P(), P())
-    sharded = _shard_map(inner, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, manual_axes={"pod"})
+    # manual over "pod" only: data/model stay auto-SPMD inside a pod
+    sharded = jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, axis_names={"pod"},
+                            check_vma=False)
 
     def train_step(params, state: DistFedState, batch):
         (new_params, new_nabla, new_ghat, new_err, mask, n_tx, dsq, ssq,

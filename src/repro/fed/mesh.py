@@ -51,6 +51,7 @@ from typing import Any, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from ..core.distributed import make_client_fold
 from ..core.simulator import FedTask
@@ -120,6 +121,41 @@ class MeshHistory(NamedTuple):
     final_params: Any            # replicated global array pytree
     mask: Optional[np.ndarray] = None     # (R, M) int8 attempted-uplink rows
     metrics: tuple = ()          # per-round merged MetricBags (host floats)
+
+
+def make_server_round(opt, mesh, quorum: float):
+    """The server half of a round: ``server_round(stacked, params, prev)``.
+
+    Folds the ``(K, ...)`` stacked shard partials with the one ``psum``
+    (``core.distributed.make_client_fold``), evaluates the quorum, and
+    advances theta where it is met. Returns ``(new_params, new_prev, met,
+    loss_sum, agg_grad_sqnorm, n_part, n_att, n_del, comp_j)``.
+    """
+    fold = make_client_fold(mesh)
+    # every shard applies the server update to its own replica: the pallas
+    # backend's update is a Mosaic kernel, which the compiler cannot
+    # partition across devices itself
+    apply_server = jax.shard_map(
+        opt.apply_server, mesh=mesh, in_specs=P(), out_specs=P(),
+        axis_names=set(mesh.axis_names), check_vma=False)
+
+    def server_round(stacked, params, prev):
+        partial_agg, loss_sum, n_part, n_att, n_del, comp_j = fold(stacked)
+        # beacons count toward quorum, drops don't: arrived =
+        # participated - (attempted - delivered), as in fed_sweep
+        arrived = n_part - (n_att - n_del)
+        met = (arrived.astype(jnp.float32)
+               >= jnp.ceil(jnp.asarray(quorum, jnp.float32)
+                           * n_part.astype(jnp.float32))) & (n_part > 0)
+        upd = apply_server(params, prev, partial_agg)
+        new_params = jax.tree_util.tree_map(
+            lambda u, t: jnp.where(met, u, t), upd, params)
+        new_prev = jax.tree_util.tree_map(
+            lambda t, tp: jnp.where(met, t, tp), params, prev)
+        return (new_params, new_prev, met, loss_sum,
+                tree_sqnorm(partial_agg), n_part, n_att, n_del, comp_j)
+
+    return server_round
 
 
 def run_mesh(cfg, task: FedTask, num_rounds: int, *,
@@ -309,26 +345,8 @@ def run_mesh(cfg, task: FedTask, num_rounds: int, *,
                               comp_blocks[i], compw_blocks[i], np.int32(k))
 
     # ------------------------------------------------- fold + server program
-    fold = make_client_fold(mesh)
     rep = replicated_sharding(mesh)
-    quo = scenario.quorum
-
-    def server_round(stacked, params, prev):
-        partial_agg, loss_sum, n_part, n_att, n_del, comp_j = fold(stacked)
-        # beacons count toward quorum, drops don't: arrived =
-        # participated - (attempted - delivered), as in fed_sweep
-        arrived = n_part - (n_att - n_del)
-        met = (arrived.astype(jnp.float32)
-               >= jnp.ceil(jnp.asarray(quo, jnp.float32)
-                           * n_part.astype(jnp.float32))) & (n_part > 0)
-        upd = opt.apply_server(params, prev, partial_agg)
-        new_params = jax.tree_util.tree_map(
-            lambda u, t: jnp.where(met, u, t), upd, params)
-        new_prev = jax.tree_util.tree_map(
-            lambda t, tp: jnp.where(met, t, tp), params, prev)
-        return (new_params, new_prev, met, loss_sum,
-                tree_sqnorm(partial_agg), n_part, n_att, n_del, comp_j)
-
+    server_round = make_server_round(opt, mesh, scenario.quorum)
     server_prog = jax.jit(server_round, out_shardings=rep)
     copy_tree = jax.jit(
         lambda t: jax.tree_util.tree_map(jnp.copy, t))

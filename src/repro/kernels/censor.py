@@ -19,7 +19,9 @@ step).
 
 Tiles are (block_rows, 128) VMEM blocks — ``block_rows=256`` by default,
 shrunk to the tensor's own row count for small tensors (``common.tile_rows``).
-Per-worker masks and the transmit flag ride in SMEM scalar blocks.
+Per-worker masks and the transmit flag ride in SMEM scalar blocks, and the
+reductions write their per-tile partials as SMEM scalars
+(``common.worker_scalar_spec`` / ``common.tile_partials_spec``).
 
 Kernels default to ``interpret=None``, resolved by
 ``common.interpret_default()``: the Pallas interpreter everywhere except a
@@ -38,7 +40,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .common import (_LANES, _pad_to_2d, _pad_to_3d, block_for,
-                     log_traffic, resolve_interpret)
+                     log_traffic, resolve_interpret,
+                     tile_partials_spec, worker_scalar_spec, worker_scalars)
 
 __all__ = [
     "censor_delta_sqnorm", "censor_select",
@@ -47,14 +50,10 @@ __all__ = [
 ]
 
 
-def _smem_scalar(index_map):
-    return pl.BlockSpec((1, 1), index_map, memory_space=pltpu.SMEM)
-
-
 # --------------------------------------------------- single-tensor kernels
 def _delta_sqnorm_kernel(g_ref, h_ref, out_ref):
     d = g_ref[...].astype(jnp.float32) - h_ref[...].astype(jnp.float32)
-    out_ref[0, 0] = jnp.sum(d * d)
+    out_ref[0, pl.program_id(0)] = jnp.sum(d * d)
 
 
 def censor_delta_sqnorm(g: jax.Array, ghat: jax.Array, *,
@@ -75,8 +74,9 @@ def censor_delta_sqnorm(g: jax.Array, ghat: jax.Array, *,
             pl.BlockSpec((block, _LANES), lambda i: (i, 0)),
             pl.BlockSpec((block, _LANES), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nr, 1), jnp.float32),
+        out_specs=pl.BlockSpec((1, nr), lambda i: (0, 0),
+                               memory_space=pltpu.SMEM),
+        out_shape=jax.ShapeDtypeStruct((1, nr), jnp.float32),
         interpret=resolve_interpret(interpret),
     )(g2, h2)
     partials = log_traffic("censor_delta_sqnorm", (g2, h2), partials)
@@ -107,7 +107,7 @@ def censor_select(g: jax.Array, ghat: jax.Array, transmit: jax.Array, *,
         _select_kernel,
         grid=(nr,),
         in_specs=[
-            _smem_scalar(lambda i: (0, 0)),
+            pl.BlockSpec((1, 1), lambda i: (0, 0), memory_space=pltpu.SMEM),
             pl.BlockSpec((block, _LANES), lambda i: (i, 0)),
             pl.BlockSpec((block, _LANES), lambda i: (i, 0)),
         ],
@@ -125,7 +125,7 @@ def _delta_sqnorm_batched_kernel(g_ref, h_ref, out_ref):
     # subtraction runs in the bank dtype (matching the reference step's
     # ``g.astype(h.dtype) - h``), the square-sum accumulates in f32
     d = (g_ref[...].astype(h_ref.dtype) - h_ref[...]).astype(jnp.float32)
-    out_ref[0, 0] = jnp.sum(d * d)
+    out_ref[0, 0, pl.program_id(1)] = jnp.sum(d * d)
 
 
 def censor_delta_sqnorm_batched(g: jax.Array, ghat: jax.Array, *,
@@ -152,17 +152,17 @@ def censor_delta_sqnorm_batched(g: jax.Array, ghat: jax.Array, *,
             pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),
             pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda w, i: (w, i)),
-        out_shape=jax.ShapeDtypeStruct((m, nr), jnp.float32),
+        out_specs=tile_partials_spec(nr),
+        out_shape=jax.ShapeDtypeStruct((m, 1, nr), jnp.float32),
         interpret=resolve_interpret(interpret),
     )(g3, h3)
     partials = log_traffic("censor_delta_sqnorm_batched", (g3, h3), partials)
-    return jnp.sum(partials, axis=1)
+    return jnp.sum(partials[:, 0], axis=1)
 
 
 def _sqnorm_batched_kernel(x_ref, out_ref):
     x = x_ref[...].astype(jnp.float32)
-    out_ref[0, 0] = jnp.sum(x * x)
+    out_ref[0, 0, pl.program_id(1)] = jnp.sum(x * x)
 
 
 def sqnorm_batched(x: jax.Array, *, block_rows: int = 256,
@@ -185,18 +185,18 @@ def sqnorm_batched(x: jax.Array, *, block_rows: int = 256,
         _sqnorm_batched_kernel,
         grid=(m, nr),
         in_specs=[pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda w, i: (w, i)),
-        out_shape=jax.ShapeDtypeStruct((m, nr), jnp.float32),
+        out_specs=tile_partials_spec(nr),
+        out_shape=jax.ShapeDtypeStruct((m, 1, nr), jnp.float32),
         interpret=resolve_interpret(interpret),
     )(x3)
     partials = log_traffic("sqnorm_batched", (x3,), partials)
-    return jnp.sum(partials, axis=1)
+    return jnp.sum(partials[:, 0], axis=1)
 
 
 def _censor_bank_advance_kernel(m_ref, g_ref, h_ref, out_ref):
     h = h_ref[...]
     g = g_ref[...].astype(h.dtype)
-    mask = m_ref[0, 0].astype(h.dtype)
+    mask = m_ref[0, 0, 0].astype(h.dtype)
     out_ref[...] = h + mask * (g - h)
 
 
@@ -219,14 +219,14 @@ def censor_bank_advance(g: jax.Array, ghat: jax.Array, mask: jax.Array, *,
     m = g.shape[0]
     g3 = _pad_to_3d(g, block_rows)
     h3 = _pad_to_3d(ghat, block_rows)
-    mk = mask.astype(jnp.float32).reshape(m, 1)
+    mk = worker_scalars(mask)
     block = block_for(g3, block_rows)
     nr = g3.shape[1] // block
     out = pl.pallas_call(
         _censor_bank_advance_kernel,
         grid=(m, nr),
         in_specs=[
-            _smem_scalar(lambda w, i: (w, 0)),
+            worker_scalar_spec(1),
             pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),
             pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),
         ],
@@ -241,7 +241,7 @@ def censor_bank_advance(g: jax.Array, ghat: jax.Array, mask: jax.Array, *,
 
 def _bank_advance_kernel(m_ref, q_ref, h_ref, out_ref):
     h = h_ref[...]
-    mask = m_ref[0, 0].astype(h.dtype)
+    mask = m_ref[0, 0, 0].astype(h.dtype)
     out_ref[...] = h + mask * q_ref[...].astype(h.dtype)
 
 
@@ -260,14 +260,14 @@ def bank_advance(ghat: jax.Array, payload: jax.Array, mask: jax.Array, *,
     m = ghat.shape[0]
     q3 = _pad_to_3d(payload, block_rows)
     h3 = _pad_to_3d(ghat, block_rows)
-    mk = mask.astype(jnp.float32).reshape(m, 1)
+    mk = worker_scalars(mask)
     block = block_for(q3, block_rows)
     nr = q3.shape[1] // block
     out = pl.pallas_call(
         _bank_advance_kernel,
         grid=(m, nr),
         in_specs=[
-            _smem_scalar(lambda w, i: (w, 0)),
+            worker_scalar_spec(1),
             pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),
             pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),
         ],

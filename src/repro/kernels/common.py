@@ -13,6 +13,11 @@ interpret-vs-Mosaic decision: every kernel module resolves
 ``interpret=None`` through it, so direct kernel calls and the ``ops.py``
 jit wrappers always agree (on TPU both lower through Mosaic; anywhere else
 both run the Pallas interpreter).
+
+Mosaic (the TPU kernel compiler) accepts a block only when its last two
+dimensions are divisible by (8, 128) or equal the array's own, and loads
+only scalars from SMEM. The per-worker spec helpers below keep to both
+rules; ``tests/test_tpu_compile.py`` compiles every kernel for a v5e.
 """
 from __future__ import annotations
 
@@ -20,6 +25,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 
@@ -68,6 +75,41 @@ def _pad_to_3d(x: jax.Array, block_rows: int) -> jax.Array:
 def block_for(x2d: jax.Array, block_rows: int) -> int:
     """The per-tile row count ``_pad_to_2d``/``_pad_to_3d`` used."""
     return min(block_rows, x2d.shape[-2])
+
+
+def worker_scalars(*cols: jax.Array) -> jax.Array:
+    """Stack (M,) per-worker scalars into the (M, 1, C) f32 SMEM operand
+    that :func:`worker_scalar_spec` blocks."""
+    return jnp.stack([c.astype(jnp.float32) for c in cols],
+                     axis=-1)[:, None, :]
+
+
+def worker_scalar_spec(width: int) -> pl.BlockSpec:
+    """SMEM block of worker ``w``'s ``width`` scalars in a ``(w, i)`` grid.
+
+    A (1, 1, width) block of an (M, 1, width) array: its last two
+    dimensions equal the array's, which a (1, width) block of (M, width)
+    would not. The kernel reads ``ref[0, 0, c]``.
+    """
+    return pl.BlockSpec((1, 1, width), lambda w, i: (w, 0, 0),
+                        memory_space=pltpu.SMEM)
+
+
+def tile_partials_spec(nr: int) -> pl.BlockSpec:
+    """SMEM output of worker ``w``'s ``nr`` tile partials in a ``(w, i)``
+    grid: a (1, 1, nr) block of an (M, 1, nr) array that stays resident
+    across the row axis ``i``; grid step ``(w, i)`` writes
+    ``ref[0, 0, i]``."""
+    return pl.BlockSpec((1, 1, nr), lambda w, i: (w, 0, 0),
+                        memory_space=pltpu.SMEM)
+
+
+def lane_dense(cols: jax.Array) -> jax.Array:
+    """(M, C) per-worker scalars broadcast to an (M, C, 128) f32 VMEM
+    operand, for kernels that hold the whole worker axis in one block and
+    need the scalars as vectors (SMEM loads only scalars)."""
+    cols = cols.astype(jnp.float32)
+    return jnp.broadcast_to(cols[:, :, None], cols.shape + (_LANES,))
 
 
 def compute_dtype(dtype) -> jnp.dtype:

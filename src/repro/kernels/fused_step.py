@@ -31,10 +31,12 @@ dtypes. Two structural choices make that hold to the bit:
     payload never touches HBM at all.
 
 ``alpha``/``beta`` are traced SMEM operands (the ``baked-traced-hparam``
-contract — one compile per shape across a whole hyperparameter grid);
-per-worker mask (+ int8 scale) ride in an ``(M, 1)``/``(M, 2)`` SMEM
-block. ``eps1`` is consumed by ``censor.decide`` between the sweeps and
-never reaches a kernel. ``interpret=None`` resolves through
+contract — one compile per shape across a whole hyperparameter grid).
+The megakernels need the per-worker mask (+ int8 scale) as vectors over
+the worker axis, and SMEM loads only scalars, so those ride lane-dense in
+an ``(M, 1, 128)``/``(M, 2, 128)`` VMEM block (``common.lane_dense``).
+``eps1`` is consumed by ``censor.decide`` between the sweeps and never
+reaches a kernel. ``interpret=None`` resolves through
 ``common.interpret_default`` like every kernel in this package.
 
 The module-level :func:`force_staged` context manager routes
@@ -53,7 +55,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .common import (_LANES, _pad_to_2d, _pad_to_3d, block_for,
-                     compute_dtype, log_traffic, resolve_interpret)
+                     compute_dtype, lane_dense, log_traffic,
+                     resolve_interpret, tile_partials_spec)
 
 __all__ = ["fused_dense_step", "fused_int8_step", "int8_stats_batched",
            "fusion_enabled", "force_staged"]
@@ -103,8 +106,8 @@ def _fused_dense_kernel(s_ref, mk_ref, g_ref, h_ref, t_ref, p_ref,
     # censor._censor_bank_advance_kernel per element
     h = h_ref[...]                                   # (M, block, 128)
     g = g_ref[...].astype(h.dtype)
-    mask = mk_ref[...].astype(h.dtype)               # (M, 1)
-    ng = h + mask[:, :, None] * (g - h)
+    mask = mk_ref[...].astype(h.dtype)               # (M, 1, 128)
+    ng = h + mask * (g - h)
     ng_ref[...] = ng
     # eq. (5): whole worker axis in-block, so this is the same axis-0
     # reduce HLO as the staged path's host-side tree_sum_leading
@@ -152,7 +155,7 @@ def fused_dense_step(g: jax.Array, ghat: jax.Array, theta: jax.Array,
     m = g.shape[0]
     shape, n = theta.shape, math.prod(theta.shape)
     s = _hb_scalars(alpha, beta, theta.dtype)
-    mk = mask.astype(jnp.float32).reshape(m, 1)
+    mk = lane_dense(mask[:, None])                    # (M, 1, 128)
     g3 = _pad_to_3d(g, block_rows)
     h3 = _pad_to_3d(ghat, block_rows)
     t2 = _pad_to_2d(theta, block_rows)
@@ -167,8 +170,7 @@ def fused_dense_step(g: jax.Array, ghat: jax.Array, theta: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 2), lambda i: (0, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((m, 1), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((m, 1, _LANES), lambda i: (0, 0, 0)),
             b3, b3, b2, b2,
         ],
         out_specs=[b3, b2, b2],
@@ -191,8 +193,10 @@ def _int8_stats_kernel(g_ref, h_ref, e_ref, sq_ref, am_ref):
     h = h_ref[...]
     pending = (g_ref[...].astype(h.dtype) - h) + e_ref[...].astype(h.dtype)
     x = pending.astype(jnp.float32)
-    sq_ref[0, 0] = jnp.sum(x * x)              # == censor._sqnorm_batched
-    am_ref[0, 0] = jnp.max(jnp.abs(pending))   # == quantize_ef._absmax
+    i = pl.program_id(1)
+    sq_ref[0, 0, i] = jnp.sum(x * x)           # == censor._sqnorm_batched
+    # == quantize_ef._absmax_kernel, widened exactly to the SMEM dtype
+    am_ref[0, 0, i] = jnp.max(jnp.abs(pending)).astype(am_ref.dtype)
 
 
 def int8_stats_batched(g: jax.Array, ghat: jax.Array, err: jax.Array, *,
@@ -224,13 +228,15 @@ def int8_stats_batched(g: jax.Array, ghat: jax.Array, err: jax.Array, *,
         grid=(m, nr),
         in_specs=[pl.BlockSpec((1, block, _LANES),
                                lambda w, i: (w, i, 0))] * 3,
-        out_specs=[pl.BlockSpec((1, 1), lambda w, i: (w, i))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((m, nr), jnp.float32),
-                   jax.ShapeDtypeStruct((m, nr), ghat.dtype)],
+        out_specs=[tile_partials_spec(nr)] * 2,
+        out_shape=[jax.ShapeDtypeStruct((m, 1, nr), jnp.float32),
+                   jax.ShapeDtypeStruct((m, 1, nr),
+                                        compute_dtype(ghat.dtype))],
         interpret=resolve_interpret(interpret),
     )(g3, h3, e3)
     sq, am = log_traffic("int8_stats_batched", (g3, h3, e3), outs)
-    return jnp.sum(sq, axis=1), jnp.max(am, axis=1)
+    return (jnp.sum(sq[:, 0], axis=1),
+            jnp.max(am[:, 0], axis=1).astype(ghat.dtype))
 
 
 # ------------------------------------------------------- int8 megakernel
@@ -241,18 +247,18 @@ def _fused_int8_kernel(s_ref, sc_ref, g_ref, h_ref, e_ref, t_ref, p_ref,
     h = h_ref[...]                                   # (M, block, 128)
     e = e_ref[...]
     pending = (g_ref[...].astype(h.dtype) - h) + e.astype(h.dtype)
-    sc = sc_ref[...]                                 # (M, 2) f32
-    scale = sc[:, 1][:, None, None]
+    mk32 = sc_ref[:, 0:1, :]                         # (M, 1, 128) f32
+    scale = sc_ref[:, 1:2, :]
     # int8 round-trip in f32, matching quantize_ef._quantize_ef_kernel;
     # the dequantized payload lives only in VMEM
     q32 = jnp.clip(jnp.round(pending.astype(jnp.float32) / scale),
                    -127, 127)
     payload = (q32 * scale).astype(pending.dtype)
-    mk = sc[:, 0].astype(pending.dtype)[:, None, None]
+    mk = mk32.astype(pending.dtype)
     ne_ref[...] = mk * (pending - payload) \
         + (1.0 - mk) * e.astype(pending.dtype)
     # bank advance from the payload, matching censor._bank_advance_kernel
-    ng = h + sc[:, 0].astype(h.dtype)[:, None, None] * payload.astype(h.dtype)
+    ng = h + mk32.astype(h.dtype) * payload.astype(h.dtype)
     ng_ref[...] = ng
     agg_ref[...] = jnp.sum(ng, axis=0)
     # eq. (4) epilogue; agg re-read through the ref so the contraction of
@@ -299,8 +305,7 @@ def fused_int8_step(g: jax.Array, ghat: jax.Array, err: jax.Array,
     m = g.shape[0]
     shape, n = theta.shape, math.prod(theta.shape)
     s = _hb_scalars(alpha, beta, theta.dtype)
-    sc = jnp.stack([mask.astype(jnp.float32),
-                    scale.astype(jnp.float32)], axis=1)       # (M, 2)
+    sc = lane_dense(jnp.stack([mask, scale], axis=1))          # (M, 2, 128)
     g3 = _pad_to_3d(g, block_rows)
     h3 = _pad_to_3d(ghat, block_rows)
     e3 = _pad_to_3d(err, block_rows)
@@ -316,8 +321,7 @@ def fused_int8_step(g: jax.Array, ghat: jax.Array, err: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 2), lambda i: (0, 0),
                          memory_space=pltpu.SMEM),
-            pl.BlockSpec((m, 2), lambda i: (0, 0),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((m, 2, _LANES), lambda i: (0, 0, 0)),
             b3, b3, b3, b2, b2,
         ],
         out_specs=[b3, b3, b2, b2],

@@ -24,16 +24,15 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from .common import (_LANES, _pad_to_3d, block_for, log_traffic,
-                     resolve_interpret)
+                     resolve_interpret, worker_scalar_spec, worker_scalars)
 
 __all__ = ["residual_ef_batched", "residual_ef_row"]
 
 
 def _residual_ef_kernel(s_ref, p_ref, q_ref, e_ref, ne_ref):
-    mask = s_ref[0, 0]
+    mask = s_ref[0, 0, 0]
     pending = p_ref[...]
     mk = mask.astype(pending.dtype)
     ne_ref[...] = mk * (pending - q_ref[...].astype(pending.dtype)) \
@@ -65,15 +64,14 @@ def residual_ef_batched(pending: jax.Array, payload: jax.Array,
     p3 = _pad_to_3d(pending, block_rows)
     q3 = _pad_to_3d(payload, block_rows)
     e3 = _pad_to_3d(err, block_rows)
-    sc = mask.astype(jnp.float32).reshape(m, 1)            # (M, 1)
+    sc = worker_scalars(mask)                               # (M, 1, 1)
     block = block_for(p3, block_rows)
     nr = p3.shape[1] // block
     new_err = pl.pallas_call(
         _residual_ef_kernel,
         grid=(m, nr),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda w, i: (w, 0),
-                         memory_space=pltpu.SMEM),
+            worker_scalar_spec(1),
             pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),
             pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),
             pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),

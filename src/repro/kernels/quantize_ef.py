@@ -25,16 +25,19 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from .common import (_LANES, _pad_to_3d, block_for, log_traffic,
-                     resolve_interpret)
+from .common import (_LANES, _pad_to_3d, block_for, compute_dtype,
+                     log_traffic, resolve_interpret,
+                     tile_partials_spec, worker_scalar_spec, worker_scalars)
 
 __all__ = ["absmax_batched", "quantize_ef_batched"]
 
 
 def _absmax_kernel(x_ref, out_ref):
-    out_ref[0, 0] = jnp.max(jnp.abs(x_ref[...]))
+    # the partial is stored widened to out_ref's dtype (SMEM holds 32-bit
+    # scalars); widening is exact, so the max is unchanged
+    out_ref[0, 0, pl.program_id(1)] = \
+        jnp.max(jnp.abs(x_ref[...])).astype(out_ref.dtype)
 
 
 def absmax_batched(x: jax.Array, *, block_rows: int = 256,
@@ -55,17 +58,17 @@ def absmax_batched(x: jax.Array, *, block_rows: int = 256,
         _absmax_kernel,
         grid=(m, nr),
         in_specs=[pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda w, i: (w, i)),
-        out_shape=jax.ShapeDtypeStruct((m, nr), x.dtype),
+        out_specs=tile_partials_spec(nr),
+        out_shape=jax.ShapeDtypeStruct((m, 1, nr), compute_dtype(x.dtype)),
         interpret=resolve_interpret(interpret),
     )(x3)
     partials = log_traffic("absmax_batched", (x3,), partials)
-    return jnp.max(partials, axis=1)
+    return jnp.max(partials[:, 0], axis=1).astype(x.dtype)
 
 
 def _quantize_ef_kernel(s_ref, p_ref, e_ref, q_ref, ne_ref):
-    mask = s_ref[0, 0]
-    scale = s_ref[0, 1]
+    mask = s_ref[0, 0, 0]
+    scale = s_ref[0, 0, 1]
     pending = p_ref[...]
     q32 = jnp.clip(jnp.round(pending.astype(jnp.float32) / scale),
                    -127, 127)
@@ -104,16 +107,14 @@ def quantize_ef_batched(pending: jax.Array, err: jax.Array,
     m = shape[0]
     p3 = _pad_to_3d(pending, block_rows)
     e3 = _pad_to_3d(err, block_rows)
-    sc = jnp.stack([mask.astype(jnp.float32),
-                    scale.astype(jnp.float32)], axis=1)   # (M, 2)
+    sc = worker_scalars(mask, scale)                       # (M, 1, 2)
     block = block_for(p3, block_rows)
     nr = p3.shape[1] // block
     payload, new_err = pl.pallas_call(
         _quantize_ef_kernel,
         grid=(m, nr),
         in_specs=[
-            pl.BlockSpec((1, 2), lambda w, i: (w, 0),
-                         memory_space=pltpu.SMEM),
+            worker_scalar_spec(2),
             pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),
             pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),
         ],

@@ -26,16 +26,15 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from .common import (_LANES, _pad_to_3d, block_for, log_traffic,
-                     resolve_interpret)
+                     resolve_interpret, worker_scalar_spec, worker_scalars)
 
 __all__ = ["select_pack_ef_batched", "select_pack_ef_row"]
 
 
 def _select_pack_ef_kernel(s_ref, p_ref, e_ref, k_ref, q_ref, ne_ref):
-    mask = s_ref[0, 0]
+    mask = s_ref[0, 0, 0]
     pending = p_ref[...]
     payload = jnp.where(k_ref[...] != 0, pending, jnp.zeros_like(pending))
     q_ref[...] = payload
@@ -73,15 +72,14 @@ def select_pack_ef_batched(pending: jax.Array, err: jax.Array,
     p3 = _pad_to_3d(pending, block_rows)
     e3 = _pad_to_3d(err, block_rows)
     k3 = _pad_to_3d(keep, block_rows)
-    sc = mask.astype(jnp.float32).reshape(m, 1)            # (M, 1)
+    sc = worker_scalars(mask)                               # (M, 1, 1)
     block = block_for(p3, block_rows)
     nr = p3.shape[1] // block
     payload, new_err = pl.pallas_call(
         _select_pack_ef_kernel,
         grid=(m, nr),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda w, i: (w, 0),
-                         memory_space=pltpu.SMEM),
+            worker_scalar_spec(1),
             pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),
             pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),
             pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),

@@ -1,19 +1,21 @@
 """Production meshes. Functions, not module constants — importing this module
 never touches jax device state (dryrun.py must set XLA_FLAGS first).
 
-Audited against the pinned jax (0.4.x, see requirements-dev.txt): the old
-``axis_types=(AxisType.Auto, ...)`` compatibility branch was dead code
-(``jax.sharding.AxisType`` does not exist on 0.4.x, and 0.4.x meshes are
-implicitly Auto), so ``make_auto_mesh`` now calls ``jax.make_mesh``
-directly. Every constructor checks the requested shape against the real
-device count and raises with the fix spelled out — a mesh request that
-cannot be satisfied must never silently degrade to fewer devices.
+Every mesh here has Auto axes: the compiler propagates shardings, as the
+trainer strategies and the client-sharded federated runtime assume.
+``jax.make_mesh`` defaults to Explicit axes, under which an unannotated
+op on a sharded operand (a Pallas call, an embedding gather) raises
+``ShardingTypeError``, so the axis types are passed explicitly. Every
+constructor checks the requested shape against the real device count and
+raises with the fix spelled out — a mesh request that cannot be satisfied
+must never silently degrade to fewer devices.
 """
 from __future__ import annotations
 
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def _require_devices(needed: int, what: str) -> None:
@@ -33,10 +35,9 @@ def _require_devices(needed: int, what: str) -> None:
 
 
 def make_auto_mesh(shape, axes):
-    """``jax.make_mesh`` with a loud device-count check (axes stay Auto —
-    the 0.4.x default; there is no axis_types argument to pass)."""
+    """``jax.make_mesh`` with Auto axes and a loud device-count check."""
     _require_devices(math.prod(shape), f"mesh {tuple(shape)}x{tuple(axes)}")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_client_mesh(num_shards: int):
@@ -49,8 +50,7 @@ def make_client_mesh(num_shards: int):
     """
     if num_shards < 1:
         raise ValueError(f"num_shards must be >= 1, got {num_shards}")
-    _require_devices(num_shards, f"client mesh ({num_shards} shards)")
-    return jax.make_mesh((num_shards,), ("clients",))
+    return make_auto_mesh((num_shards,), ("clients",))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -7,6 +7,7 @@ import argparse
 
 from ..configs import ARCHS, get
 from ..train.trainer import TrainConfig, train
+from .compile_cache import enable_compile_cache
 from .mesh import make_local_mesh
 
 
@@ -32,6 +33,7 @@ def main() -> None:
     ap.add_argument("--ckpt-every", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -195,7 +195,6 @@ def run_fed_sweep(cfg, task: FedTask,
         # partition: no collectives, each device scans its own block
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as _P
-        from ..core.distributed import _shard_map
         axis = mesh.axis_names[0]
         n_shards = mesh.devices.size
         if len(points) % n_shards:
@@ -204,9 +203,10 @@ def run_fed_sweep(cfg, task: FedTask,
                 "needs the point count divisible by the shard count — pad "
                 "the grid or drop mesh=")
         pts_dev = jax.device_put(pts_dev, NamedSharding(mesh, _P(axis)))
-        program = jax.jit(_shard_map(inner, mesh, in_specs=(_P(axis),),
-                                     out_specs=_P(axis),
-                                     manual_axes={axis}))
+        program = jax.jit(jax.shard_map(inner, mesh=mesh,
+                                        in_specs=(_P(axis),),
+                                        out_specs=_P(axis),
+                                        axis_names={axis}, check_vma=False))
     obj, gsq, transmit, delivered, participate, met = \
         jax.tree_util.tree_map(np.asarray, program(pts_dev))
 

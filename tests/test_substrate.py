@@ -140,3 +140,24 @@ def test_comm_stats_savings():
     assert float(s.savings_vs_dense()) == pytest.approx(0.75)
     assert float(s.uplink_bytes) == pytest.approx(1000.0)
     assert int(s.downlink_count) == 10
+
+
+# ---------------------------------------------------------- compile cache
+@pytest.mark.parametrize("placed", [True, False])
+def test_compile_cache_placement(tmp_path, monkeypatch, placed):
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        where = compile_cache.enable_compile_cache()
+        now = jax.config.jax_compilation_cache_dir
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    if placed:   # JAX reads the variable itself; nothing is set in code
+        assert where == str(tmp_path) and now == before
+    else:
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert where == now == os.path.join(root, ".jax_cache")
