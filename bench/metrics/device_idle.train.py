@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the chip during
+the trainer's window call."""
+
+
+def read(ctx, outcome, trace):
+    chips = len(ctx.devices)
+    return 100.0 * (1.0 - trace.mean_busy_s(chips) / trace.window_s)
