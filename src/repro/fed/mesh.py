@@ -60,7 +60,7 @@ from ..launch.mesh import make_client_mesh
 from ..launch.sharding import (client_shard_sizes, per_device_views,
                                replicated_sharding, stack_shards)
 from ..lint import draw_exact
-from ..obs import compile_log
+from ..obs import profile
 from ..opt import AdaptiveCensor, as_optimizer
 from ..opt.api import StepStats
 from .channel import ChannelConfig
@@ -216,221 +216,264 @@ def run_mesh(cfg, task: FedTask, num_rounds: int, *,
     Returns:
       A ``MeshHistory``.
     """
-    opt = as_optimizer(cfg)
-    if getattr(opt, "censor", None) is None or \
-            getattr(opt, "server", None) is None:
-        raise TypeError(
-            "run_mesh drives the censor/transport stages through "
-            "shard_step, so it needs a ComposedOptimizer (or an optimizer "
-            f"exposing the stage attributes), not {type(opt).__name__}")
-    if opt.granularity != "global":
-        raise NotImplementedError("run_mesh supports granularity='global'")
-    if isinstance(opt.censor, AdaptiveCensor):
-        raise NotImplementedError(
-            "run_mesh rejects adaptive censoring (cohort-wide EMA is "
-            "ill-defined under partial participation; see fed_sweep)")
-    scenario = scenario if scenario is not None else MeshScenario()
-    if isinstance(population, Population):
-        population = population.as_vector()
-    channel = channel if channel is not None else ChannelConfig.ideal()
-    energy = energy if energy is not None else EnergyModel()
+    with profile.annotate("run_mesh"):
+        with profile.annotate("run_mesh/setup"):
+            opt = as_optimizer(cfg)
+            if getattr(opt, "censor", None) is None or \
+                    getattr(opt, "server", None) is None:
+                raise TypeError(
+                    "run_mesh drives the censor/transport stages through "
+                    "shard_step, so it needs a ComposedOptimizer (or an "
+                    "optimizer exposing the stage attributes), not "
+                    f"{type(opt).__name__}")
+            if opt.granularity != "global":
+                raise NotImplementedError(
+                    "run_mesh supports granularity='global'")
+            if isinstance(opt.censor, AdaptiveCensor):
+                raise NotImplementedError(
+                    "run_mesh rejects adaptive censoring (cohort-wide EMA is "
+                    "ill-defined under partial participation; see fed_sweep)")
+            scenario = scenario if scenario is not None else MeshScenario()
+            if isinstance(population, Population):
+                population = population.as_vector()
+            channel = channel if channel is not None else \
+                ChannelConfig.ideal()
+            energy = energy if energy is not None else EnergyModel()
 
-    m = jax.tree_util.tree_leaves(task.worker_data)[0].shape[0]
-    if opt.num_workers != m:
-        raise ValueError(f"cfg.num_workers={opt.num_workers} != task M={m}")
-    if population is not None and population.num_clients != m:
-        raise ValueError(
-            f"population has {population.num_clients} clients, task has {m}")
-    mesh = mesh if mesh is not None else make_client_mesh(1)
-    m_local = client_shard_sizes(m, mesh)
-    devices = list(mesh.devices.flat)
-    k_shards = len(devices)
-    compile_log.record("fed.mesh", "run_mesh")
+            m = jax.tree_util.tree_leaves(task.worker_data)[0].shape[0]
+            if opt.num_workers != m:
+                raise ValueError(
+                    f"cfg.num_workers={opt.num_workers} != task M={m}")
+            if population is not None and population.num_clients != m:
+                raise ValueError(
+                    f"population has {population.num_clients} clients, "
+                    f"task has {m}")
+            mesh = mesh if mesh is not None else make_client_mesh(1)
+            m_local = client_shard_sizes(m, mesh)
+            devices = list(mesh.devices.flat)
+            k_shards = len(devices)
 
-    # ---------------------------------------------- per-shard constant data
-    def _block(x, i):
-        return x[i * m_local:(i + 1) * m_local]
+            # -------------------------------------- per-shard constant data
+            def _block(x, i):
+                return x[i * m_local:(i + 1) * m_local]
 
-    data_blocks, ids_blocks, comp_blocks, compw_blocks = [], [], [], []
-    comp = np.zeros((m,), np.float32) if population is None else \
-        np.asarray(population.compute_mean_s, np.float32)
-    compw = np.zeros((m,), np.float32) if population is None else \
-        np.asarray(population.compute_w, np.float32)
-    for i, dev in enumerate(devices):
-        data_blocks.append(jax.device_put(jax.tree_util.tree_map(
-            lambda x: _block(x, i), task.worker_data), dev))
-        ids_blocks.append(jax.device_put(
-            jnp.arange(i * m_local, (i + 1) * m_local, dtype=jnp.uint32),
-            dev))
-        comp_blocks.append(jax.device_put(_block(comp, i), dev))
-        compw_blocks.append(jax.device_put(_block(compw, i), dev))
+            data_blocks, ids_blocks = [], []
+            comp_blocks, compw_blocks = [], []
+            comp = np.zeros((m,), np.float32) if population is None else \
+                np.asarray(population.compute_mean_s, np.float32)
+            compw = np.zeros((m,), np.float32) if population is None else \
+                np.asarray(population.compute_w, np.float32)
+            for i, dev in enumerate(devices):
+                data_blocks.append(jax.device_put(jax.tree_util.tree_map(
+                    lambda x: _block(x, i), task.worker_data), dev))
+                ids_blocks.append(jax.device_put(
+                    jnp.arange(i * m_local, (i + 1) * m_local,
+                               dtype=jnp.uint32), dev))
+                comp_blocks.append(jax.device_put(_block(comp, i), dev))
+                compw_blocks.append(jax.device_put(_block(compw, i), dev))
 
-    opt_local = dataclasses.replace(opt, num_workers=m_local)
-    part_p, loss_p = scenario.participation, scenario.loss_prob
-    sync_draws, seed = scenario.sync_draws, scenario.seed
+            opt_local = dataclasses.replace(opt, num_workers=m_local)
+            part_p, loss_p = scenario.participation, scenario.loss_prob
+            sync_draws, seed = scenario.sync_draws, scenario.seed
 
-    # --------------------------------------------------- shard round program
-    @draw_exact
-    def shard_round(state, params, data, ids, comp_s, compw_s, round_idx):
-        # the contiguous-block vmap: bit-stable under resplitting the
-        # leading axis (the only regrouping sharding performs) and
-        # identical to simulator.run's batching — see module docstring
-        # repro-lint: disable=vmap-in-draw-exact -- contiguous-block vmap
-        # is the anchor-(a) batching; lax.map would break bit-identity
-        # with simulator.run's vmapped grads
-        grads = jax.vmap(task.grad_fn, in_axes=(None, 0))(params, data)
-        if sync_draws:
-            participate = channel_mask = None
-        else:
-            rkey = jax.random.fold_in(jax.random.PRNGKey(seed), round_idx)
+            # ----------------------------------------- shard round program
+            @draw_exact
+            def shard_round(state, params, data, ids, comp_s, compw_s,
+                            round_idx):
+                # the contiguous-block vmap: bit-stable under resplitting
+                # the leading axis (the only regrouping sharding performs)
+                # and identical to simulator.run's batching — see module
+                # docstring
+                # repro-lint: disable=vmap-in-draw-exact -- contiguous-block
+                # vmap is the anchor-(a) batching; lax.map would break
+                # bit-identity with simulator.run's vmapped grads
+                grads = jax.vmap(task.grad_fn, in_axes=(None, 0))(params,
+                                                                  data)
+                if sync_draws:
+                    participate = channel_mask = None
+                else:
+                    rkey = jax.random.fold_in(jax.random.PRNGKey(seed),
+                                              round_idx)
 
-            def draws(cid):
-                ck = jax.random.fold_in(rkey, cid)
-                return (jax.random.uniform(jax.random.fold_in(ck, 0)),
-                        jax.random.uniform(jax.random.fold_in(ck, 1)))
+                    def draws(cid):
+                        ck = jax.random.fold_in(rkey, cid)
+                        return (jax.random.uniform(jax.random.fold_in(ck, 0)),
+                                jax.random.uniform(jax.random.fold_in(ck, 1)))
 
-            # repro-lint: disable=vmap-in-draw-exact -- each lane's draw
-            # is keyed by (seed, round, absolute client id) alone, so
-            # batching cannot regroup or leak across lanes
-            u_part, u_drop = jax.vmap(draws)(ids)
-            participate = (u_part < part_p).astype(jnp.float32)
-            channel_mask = (u_drop >= loss_p).astype(jnp.float32)
-        new_state, partial_agg, st = opt_local.shard_step(
-            state, params, grads, worker_ids=ids,
-            participate=participate, channel_mask=channel_mask)
-        # repro-lint: disable=vmap-in-draw-exact -- same contiguous-block
-        # batching as the grads; the per-shard sum is the psum partial
-        losses = jax.vmap(task.loss_fn, in_axes=(None, 0))(params, data)
-        loss_part = jnp.sum(losses)
-        if participate is None:
-            n_part = jnp.asarray(m_local, jnp.int32)
-            comp_active = comp_s
-        else:
-            n_part = jnp.sum(participate.astype(jnp.int32))
-            comp_active = jnp.where(participate != 0, comp_s, 0.0)
-        n_att = jnp.sum(st.attempted.astype(jnp.int32))
-        n_del = jnp.sum(st.delivered.astype(jnp.int32))
-        wall_local = jnp.max(comp_active) if m_local else \
-            jnp.zeros((), jnp.float32)
-        comp_j = jnp.sum(comp_active * compw_s)
-        partials = (partial_agg, loss_part, n_part, n_att, n_del, comp_j)
-        stacked_row = jax.tree_util.tree_map(lambda v: v[None], partials)
-        out = (new_state, stacked_row, st.attempted, wall_local)
-        if collect_metrics:
-            from ..obs.metrics import step_metrics
-            bag = step_metrics(opt_local, new_state, StepStats(
-                mask=st.mask, delta_sq=st.delta_sq, step_sq=st.step_sq,
-                agg_grad_sqnorm=tree_sqnorm(partial_agg)))
-            out = out + (bag,)
-        return out
+                    # repro-lint: disable=vmap-in-draw-exact -- each lane's
+                    # draw is keyed by (seed, round, absolute client id)
+                    # alone, so batching cannot regroup or leak across lanes
+                    u_part, u_drop = jax.vmap(draws)(ids)
+                    participate = (u_part < part_p).astype(jnp.float32)
+                    channel_mask = (u_drop >= loss_p).astype(jnp.float32)
+                new_state, partial_agg, st = opt_local.shard_step(
+                    state, params, grads, worker_ids=ids,
+                    participate=participate, channel_mask=channel_mask)
+                # repro-lint: disable=vmap-in-draw-exact -- same
+                # contiguous-block batching as the grads; the per-shard sum
+                # is the psum partial
+                losses = jax.vmap(task.loss_fn, in_axes=(None, 0))(params,
+                                                                   data)
+                loss_part = jnp.sum(losses)
+                if participate is None:
+                    n_part = jnp.asarray(m_local, jnp.int32)
+                    comp_active = comp_s
+                else:
+                    n_part = jnp.sum(participate.astype(jnp.int32))
+                    comp_active = jnp.where(participate != 0, comp_s, 0.0)
+                n_att = jnp.sum(st.attempted.astype(jnp.int32))
+                n_del = jnp.sum(st.delivered.astype(jnp.int32))
+                wall_local = jnp.max(comp_active) if m_local else \
+                    jnp.zeros((), jnp.float32)
+                comp_j = jnp.sum(comp_active * compw_s)
+                partials = (partial_agg, loss_part, n_part, n_att, n_del,
+                            comp_j)
+                stacked_row = jax.tree_util.tree_map(lambda v: v[None],
+                                                     partials)
+                out = (new_state, stacked_row, st.attempted, wall_local)
+                if collect_metrics:
+                    from ..obs.metrics import step_metrics
+                    bag = step_metrics(opt_local, new_state, StepStats(
+                        mask=st.mask, delta_sq=st.delta_sq,
+                        step_sq=st.step_sq,
+                        agg_grad_sqnorm=tree_sqnorm(partial_agg)))
+                    out = out + (bag,)
+                return out
 
-    donate_args = (0,) if donate else ()
-    if bake_data:
-        def _baked(d, ii):
-            def fn(state, params, comp_s, compw_s, round_idx):
-                return shard_round(state, params, d, ii, comp_s, compw_s,
-                                   round_idx)
-            return jax.jit(fn, donate_argnums=donate_args)
-        progs = [_baked(data_blocks[i], ids_blocks[i])
-                 for i in range(k_shards)]
+            donate_args = (0,) if donate else ()
+            if bake_data:
+                def _baked(d, ii):
+                    def fn(state, params, comp_s, compw_s, round_idx):
+                        return shard_round(state, params, d, ii, comp_s,
+                                           compw_s, round_idx)
+                    return jax.jit(fn, donate_argnums=donate_args)
+                progs = [_baked(data_blocks[i], ids_blocks[i])
+                         for i in range(k_shards)]
 
-        def run_shard(i, state, pview, k):
-            return progs[i](state, pview, comp_blocks[i], compw_blocks[i],
-                            np.int32(k))
-    else:
-        shard_prog = jax.jit(shard_round, donate_argnums=donate_args)
+                def run_shard(i, state, pview, k):
+                    return progs[i](state, pview, comp_blocks[i],
+                                    compw_blocks[i], np.int32(k))
+            else:
+                shard_prog = jax.jit(shard_round, donate_argnums=donate_args)
 
-        def run_shard(i, state, pview, k):
-            return shard_prog(state, pview, data_blocks[i], ids_blocks[i],
-                              comp_blocks[i], compw_blocks[i], np.int32(k))
+                def run_shard(i, state, pview, k):
+                    return shard_prog(state, pview, data_blocks[i],
+                                      ids_blocks[i], comp_blocks[i],
+                                      compw_blocks[i], np.int32(k))
 
-    # ------------------------------------------------- fold + server program
-    rep = replicated_sharding(mesh)
-    server_round = make_server_round(opt, mesh, scenario.quorum)
-    server_prog = jax.jit(server_round, out_shardings=rep)
-    copy_tree = jax.jit(
-        lambda t: jax.tree_util.tree_map(jnp.copy, t))
+            # --------------------------------------- fold + server program
+            rep = replicated_sharding(mesh)
+            server_round = make_server_round(opt, mesh, scenario.quorum)
+            server_prog = jax.jit(server_round, out_shardings=rep)
+            copy_tree = jax.jit(
+                lambda t: jax.tree_util.tree_map(jnp.copy, t))
 
-    # --------------------------------------------------------- init + loop
-    params_rep = jax.device_put(task.init_params, rep)
-    prev_rep = jax.device_put(
-        jax.tree_util.tree_map(jnp.copy, task.init_params), rep)
-    states = []
-    for i, dev in enumerate(devices):
-        params_dev = jax.device_put(task.init_params, dev)
-        states.append(jax.jit(opt_local.init)(params_dev))
+            # ------------------------------------------------- shard states
+            params_rep = jax.device_put(task.init_params, rep)
+            prev_rep = jax.device_put(
+                jax.tree_util.tree_map(jnp.copy, task.init_params), rep)
+            states = []
+            for i, dev in enumerate(devices):
+                params_dev = jax.device_put(task.init_params, dev)
+                states.append(jax.jit(opt_local.init)(params_dev))
 
-    payload = opt.transport.payload_bytes(task.init_params)
-    uplink_air = 0.0
-    if np.isfinite(channel.uplink_rate_bps):
-        uplink_air = channel.overhead_s + 8.0 * payload / \
-            channel.uplink_rate_bps
-    downlink_air = channel.downlink_time(payload)
+            payload = opt.transport.payload_bytes(task.init_params)
+            uplink_air = 0.0
+            if np.isfinite(channel.uplink_rate_bps):
+                uplink_air = channel.overhead_s + 8.0 * payload / \
+                    channel.uplink_rate_bps
+            downlink_air = channel.downlink_time(payload)
 
-    objective, gsq_hist, met_hist = [], [], []
-    n_part_h, n_att_h, n_del_h = [], [], []
-    wall, energy_cum, t, joules = [], [], 0.0, 0.0
-    mask_rows: list[np.ndarray] = []
-    bags: list[dict] = []
+        objective, gsq_hist, met_hist = [], [], []
+        n_part_h, n_att_h, n_del_h = [], [], []
+        wall, energy_cum, t, joules = [], [], 0.0, 0.0
+        mask_rows: list[np.ndarray] = []
+        bags: list[dict] = []
 
-    for k in range(num_rounds):
-        params_views = per_device_views(params_rep, mesh)
-        outs = [run_shard(i, states[i], params_views[i], k)
-                for i in range(k_shards)]
-        states = [o[0] for o in outs]
-        stacked = stack_shards([o[1] for o in outs], mesh)
-        (params_rep, prev_rep, met, loss_sum, gsq, n_part, n_att, n_del,
-         comp_j) = server_prog(stacked, params_rep, prev_rep)
+        for k in range(num_rounds):
+            with profile.step("run_mesh/round", k):
+                with profile.annotate("run_mesh/dispatch"):
+                    params_views = per_device_views(params_rep, mesh)
+                    outs = [run_shard(i, states[i], params_views[i], k)
+                            for i in range(k_shards)]
+                    states = [o[0] for o in outs]
+                    stacked = stack_shards([o[1] for o in outs], mesh)
+                    (params_rep, prev_rep, met, loss_sum, gsq, n_part, n_att,
+                     n_del, comp_j) = server_prog(stacked, params_rep,
+                                                  prev_rep)
 
-        # shard states carry theta^{k-1} for the next eq.-(8) step norm;
-        # quorum may have frozen it, so overwrite from the server's
-        # (replicated) new_prev. Copy under donation: the raw per-device
-        # views alias prev_rep's buffers, which the next round would
-        # donate away while server_round still needs them.
-        prev_views = per_device_views(prev_rep, mesh)
-        states = [
-            st._replace(prev_params=copy_tree(pv) if donate else pv)
-            for st, pv in zip(states, prev_views)]
+                    # shard states carry theta^{k-1} for the next eq.-(8)
+                    # step norm; quorum may have frozen it, so overwrite
+                    # from the server's (replicated) new_prev. Copy under
+                    # donation: the raw per-device views alias prev_rep's
+                    # buffers, which the next round would donate away while
+                    # server_round still needs them.
+                    prev_views = per_device_views(prev_rep, mesh)
+                    states = [
+                        st._replace(prev_params=copy_tree(pv) if donate
+                                    else pv)
+                        for st, pv in zip(states, prev_views)]
 
-        objective.append(float(loss_sum))
-        gsq_hist.append(float(gsq))
-        met_hist.append(bool(met))
-        n_part_h.append(int(n_part))
-        n_att_h.append(int(n_att))
-        n_del_h.append(int(n_del))
-        t += (max(float(o[3]) for o in outs)
-              + (uplink_air if int(n_att) else 0.0) + downlink_air)
-        wall.append(t)
-        joules += float(energy.round_energy(int(n_att), int(n_part),
-                                            payload)) + float(comp_j)
-        energy_cum.append(joules)
-        if collect_mask:
-            mask_rows.append(np.concatenate(
-                [np.asarray(o[2]) for o in outs]).astype(np.int8))
-        if collect_metrics:
-            from ..obs.metrics import merge_shard_bags
-            shard_bags = [
-                {kk: np.asarray(v) for kk, v in o[4].items()} for o in outs]
-            merged = merge_shard_bags(shard_bags,
-                                      weights=[m_local] * k_shards)
-            merged = {kk: float(np.asarray(v)) for kk, v in merged.items()}
-            merged["agg_grad_sqnorm"] = float(gsq)
-            bags.append(merged)
+                # the round's reads back to the host, one array at a time:
+                # 7 server scalars, then per shard its slowest client's
+                # compute time, its mask rows and each entry of its bag
+                reads = 7 + k_shards * (1 + int(collect_mask) + (
+                    len(outs[0][4]) if collect_metrics else 0))
+                with profile.annotate("run_mesh/sync", reads=reads):
+                    loss_h = float(loss_sum)
+                    gsq_h = float(gsq)
+                    met_h = bool(met)
+                    part_h, att_h, del_h = int(n_part), int(n_att), \
+                        int(n_del)
+                    comp_h = float(comp_j)
+                    slowest = max(float(o[3]) for o in outs)
+                    if collect_mask:
+                        shard_rows = [np.asarray(o[2]) for o in outs]
+                    if collect_metrics:
+                        shard_bags = [{kk: np.asarray(v)
+                                       for kk, v in o[4].items()}
+                                      for o in outs]
 
-    att = np.asarray(n_att_h, np.int64)
-    return MeshHistory(
-        objective=np.asarray(objective),
-        agg_grad_sqnorm=np.asarray(gsq_hist),
-        quorum_met=np.asarray(met_hist, bool),
-        participated=np.asarray(n_part_h, np.int64),
-        attempted=att,
-        delivered=np.asarray(n_del_h, np.int64),
-        comm_cum=np.cumsum(att),
-        delivered_cum=np.cumsum(np.asarray(n_del_h, np.int64)),
-        bytes_cum=np.cumsum(att * payload),
-        energy_cum=np.asarray(energy_cum),
-        wall_clock=np.asarray(wall),
-        final_params=params_rep,
-        mask=np.stack(mask_rows) if mask_rows else None,
-        metrics=tuple(bags),
-    )
+                with profile.annotate("run_mesh/account"):
+                    objective.append(loss_h)
+                    gsq_hist.append(gsq_h)
+                    met_hist.append(met_h)
+                    n_part_h.append(part_h)
+                    n_att_h.append(att_h)
+                    n_del_h.append(del_h)
+                    t += (slowest + (uplink_air if att_h else 0.0)
+                          + downlink_air)
+                    wall.append(t)
+                    joules += float(energy.round_energy(
+                        att_h, part_h, payload)) + comp_h
+                    energy_cum.append(joules)
+                    if collect_mask:
+                        mask_rows.append(
+                            np.concatenate(shard_rows).astype(np.int8))
+                    if collect_metrics:
+                        from ..obs.metrics import merge_shard_bags
+                        merged = merge_shard_bags(
+                            shard_bags, weights=[m_local] * k_shards)
+                        merged = {kk: float(np.asarray(v))
+                                  for kk, v in merged.items()}
+                        merged["agg_grad_sqnorm"] = gsq_h
+                        bags.append(merged)
+
+        with profile.annotate("run_mesh/history"):
+            att = np.asarray(n_att_h, np.int64)
+            return MeshHistory(
+                objective=np.asarray(objective),
+                agg_grad_sqnorm=np.asarray(gsq_hist),
+                quorum_met=np.asarray(met_hist, bool),
+                participated=np.asarray(n_part_h, np.int64),
+                attempted=att,
+                delivered=np.asarray(n_del_h, np.int64),
+                comm_cum=np.cumsum(att),
+                delivered_cum=np.cumsum(np.asarray(n_del_h, np.int64)),
+                bytes_cum=np.cumsum(att * payload),
+                energy_cum=np.asarray(energy_cum),
+                wall_clock=np.asarray(wall),
+                final_params=params_rep,
+                mask=np.stack(mask_rows) if mask_rows else None,
+                metrics=tuple(bags),
+            )

@@ -11,7 +11,7 @@ Two halves, one package:
   * **Host-side sinks**: ``obs.runlog`` (JSONL event writer),
     ``obs.compile_log`` (process-wide trace/retrace counters, the
     generalization of ``kernels/ops.trace_counts``), ``obs.profile``
-    (profiler annotations + trace capture), ``obs.bench``
+    (named host spans on the profiler timeline), ``obs.bench``
     (schema-versioned BENCH_*.json artifacts), and ``obs.hlo_report``
     (trip-count-weighted collective/HBM hotspot reports from compiled
     HLO).
@@ -22,7 +22,7 @@ from . import bench, compile_log, metrics, profile, runlog
 from .compile_log import TrackedCounts
 from .metrics import MetricBag, metric_names, stage_metrics, step_metrics, \
     summarize
-from .profile import annotate, annotate_fn, named_scope, trace
+from .profile import annotate, named_scope, step
 from .runlog import EVENT_SCHEMA_VERSION, RunLog, read_jsonl
 
 
@@ -37,6 +37,6 @@ def __getattr__(name: str):
 __all__ = [
     "bench", "compile_log", "hlo_report", "metrics", "profile", "runlog",
     "TrackedCounts", "MetricBag", "metric_names", "stage_metrics",
-    "step_metrics", "summarize", "annotate", "annotate_fn", "named_scope",
-    "trace", "RunLog", "read_jsonl", "EVENT_SCHEMA_VERSION",
+    "step_metrics", "summarize", "annotate", "named_scope", "step",
+    "RunLog", "read_jsonl", "EVENT_SCHEMA_VERSION",
 ]

@@ -1,30 +1,26 @@
-"""Profiler hooks: named annotations + trace capture around hot paths.
+"""Profiler spans: named host-side phases of the program's entry points.
 
-Thin, dependency-free wrappers over ``jax.profiler`` so call sites never
-touch it directly:
+Thin wrappers over ``jax.profiler`` so call sites never touch it directly:
 
-  * :func:`annotate` — a host-side ``TraceAnnotation`` context: the
-    wrapped block shows up as a named span on the profiler timeline.
-    Use it around *dispatch* (a composed step, a sweep partition launch);
-    for *in-graph* attribution the dispatch layer already wraps every
-    Pallas tree kernel in ``jax.named_scope`` (``kernels/<name>`` — see
-    ``kernels/ops._dispatch``) and the composed step in
-    ``chb_step[<backend>]``, which is HLO metadata only and therefore
-    free and bit-exact.
-  * :func:`trace` — capture a profiler trace directory for a block
-    (viewable in TensorBoard / Perfetto). No-ops gracefully when the
-    runtime lacks profiler support, so library code can call it
-    unconditionally.
-  * :func:`annotate_fn` — decorator form of :func:`annotate`.
+  * :func:`annotate` — a ``TraceAnnotation`` context: the wrapped block
+    shows up as a named span on the profiler's host timeline, on the same
+    clock as the device's operations, with integer keyword ``stats``
+    attached to the event (``annotate("run_mesh/sync", reads=9)``).
+  * :func:`step` — the same over ``StepTraceAnnotation``: one iteration of
+    a loop, carrying its ``step_num``.
 
-None of these affect numerics: annotations are metadata, and trace
-capture only observes.
+Spans cost about a microsecond each when no profiler session is running
+and change nothing the program computes. ``fed.run_mesh`` and
+``train.trainer.train`` open them at their phase boundaries (the names are
+listed in docs/observability.md); the benchmark's readers find them by name
+in a traced window.
+
+For *in-graph* attribution the dispatch layer wraps every Pallas tree
+kernel in ``jax.named_scope`` (``kernels/<name>`` — see
+``kernels/ops._dispatch``) and the composed step in ``chb_step[<backend>]``;
+that is HLO metadata only.
 """
 from __future__ import annotations
-
-import contextlib
-import functools
-from typing import Iterator, Optional
 
 import jax
 
@@ -32,52 +28,11 @@ import jax
 named_scope = jax.named_scope
 
 
-@contextlib.contextmanager
-def annotate(name: str) -> Iterator[None]:
-    """Named host-side span on the profiler timeline (no-op if absent)."""
-    ann = getattr(jax.profiler, "TraceAnnotation", None)
-    if ann is None:                       # pragma: no cover - old jax
-        yield
-        return
-    with ann(name):
-        yield
+def annotate(name: str, **stats: int) -> jax.profiler.TraceAnnotation:
+    """A named host span; ``stats`` are ints known when it opens."""
+    return jax.profiler.TraceAnnotation(name, **stats)
 
 
-def annotate_fn(name: Optional[str] = None):
-    """Decorator: run the function under :func:`annotate`."""
-    def deco(fn):
-        label = name or fn.__name__
-
-        @functools.wraps(fn)
-        def wrapped(*args, **kwargs):
-            with annotate(label):
-                return fn(*args, **kwargs)
-        return wrapped
-    return deco
-
-
-@contextlib.contextmanager
-def trace(log_dir: str, *, create_perfetto_link: bool = False,
-          create_perfetto_trace: bool = False) -> Iterator[None]:
-    """Capture a profiler trace for the block into ``log_dir``.
-
-    Wraps ``jax.profiler.trace``; degrades to a no-op (still executing the
-    block) if the runtime's profiler is unavailable, so benchmarks can
-    offer ``--profile DIR`` without a hard dependency.
-    """
-    tracer = getattr(jax.profiler, "trace", None)
-    if tracer is None:                    # pragma: no cover - old jax
-        yield
-        return
-    try:
-        ctx = tracer(log_dir, create_perfetto_link=create_perfetto_link,
-                     create_perfetto_trace=create_perfetto_trace)
-        ctx.__enter__()
-    except Exception:                     # pragma: no cover - backend quirk
-        # profiling must never take the run down with it
-        yield
-        return
-    try:
-        yield
-    finally:
-        ctx.__exit__(None, None, None)
+def step(name: str, step_num: int) -> jax.profiler.StepTraceAnnotation:
+    """A named host span for one loop iteration, numbered ``step_num``."""
+    return jax.profiler.StepTraceAnnotation(name, step_num=step_num)

@@ -22,6 +22,7 @@ from ..data import lm_data
 from ..launch import sharding as shr
 from ..launch.mesh import dp_axes
 from ..models import model
+from ..obs import profile
 
 
 @dataclasses.dataclass
@@ -88,63 +89,73 @@ def make_fed_config(tc: TrainConfig, mesh=None) -> FedOptConfig:
 
 def train(cfg: ModelConfig, tc: TrainConfig, mesh=None, verbose=True):
     """Returns (params, state, history list of metric dicts)."""
-    fcfg = make_optimizer(tc, mesh)
-    m = fcfg.num_workers
+    with profile.annotate("train"):
+        with profile.annotate("train/setup"):
+            fcfg = make_optimizer(tc, mesh)
+            m = fcfg.num_workers
 
-    act = None
-    if mesh is not None:
-        # inside the pod-manual region only auto axes may appear in
-        # sharding constraints
-        axes = ("data",) if tc.strategy == "pod" else dp_axes(mesh)
-        act = NamedSharding(mesh, P(axes))
+            act = None
+            if mesh is not None:
+                # inside the pod-manual region only auto axes may appear in
+                # sharding constraints
+                axes = ("data",) if tc.strategy == "pod" else dp_axes(mesh)
+                act = NamedSharding(mesh, P(axes))
 
-    def loss_fn(params, batch):
-        return model.train_loss(params, cfg, batch, moe_mode=tc.moe_mode,
-                                remat=tc.remat, act_spec=act)[0]
+            def loss_fn(params, batch):
+                return model.train_loss(params, cfg, batch,
+                                        moe_mode=tc.moe_mode,
+                                        remat=tc.remat, act_spec=act)[0]
 
-    params = model.init_params(jax.random.PRNGKey(tc.seed), cfg)
-    if mesh is not None:
-        shardings = shr.params_shardings(
-            jax.eval_shape(lambda: params), mesh,
-            fsdp_axes=dp_axes(mesh) if tc.strategy == "scan" else ("data",),
-            gather_safe=(tc.strategy == "pod"))
-        params = jax.tree_util.tree_map(jax.device_put, params,
-                                        shardings)
+            params = model.init_params(jax.random.PRNGKey(tc.seed), cfg)
+            if mesh is not None:
+                shardings = shr.params_shardings(
+                    jax.eval_shape(lambda: params), mesh,
+                    fsdp_axes=dp_axes(mesh) if tc.strategy == "scan"
+                    else ("data",),
+                    gather_safe=(tc.strategy == "pod"))
+                params = jax.tree_util.tree_map(jax.device_put, params,
+                                                shardings)
 
-    if tc.strategy == "pod":
-        assert mesh is not None and "pod" in mesh.axis_names
-        state = distributed.init_pod_state(fcfg, params, mesh)
-        step_fn = distributed.make_pod_step(fcfg, loss_fn, mesh)
-        workers_for_data = None
-    else:
-        state = distributed.init_scan_state(fcfg, params)
-        step_fn = distributed.make_scan_step(fcfg, loss_fn)
-        workers_for_data = m
+            if tc.strategy == "pod":
+                assert mesh is not None and "pod" in mesh.axis_names
+                state = distributed.init_pod_state(fcfg, params, mesh)
+                step_fn = distributed.make_pod_step(fcfg, loss_fn, mesh)
+                workers_for_data = None
+            else:
+                state = distributed.init_scan_state(fcfg, params)
+                step_fn = distributed.make_scan_step(fcfg, loss_fn)
+                workers_for_data = m
 
-    step_fn = jax.jit(step_fn,
-                      donate_argnums=(0, 1) if tc.donate else ())
-    data = lm_data.batch_iterator(cfg, global_batch=tc.global_batch,
-                                  seq_len=tc.seq_len,
-                                  num_workers=workers_for_data, seed=tc.seed)
-    history = []
-    t0 = time.time()
-    for step in range(tc.steps):
-        batch = next(data)
-        params, state, metrics = step_fn(params, state, batch)
-        if step % tc.log_every == 0 or step == tc.steps - 1:
-            rec = {k: float(v) for k, v in metrics.items()}
-            rec.update(step=step,
-                       comms=int(state.comm.total_uplinks),
-                       comm_savings=float(state.comm.savings_vs_dense()),
-                       wall_s=round(time.time() - t0, 1))
-            history.append(rec)
-            if verbose:
-                print(f"step {step:5d} loss={rec['loss']:.4f} "
-                      f"tx={rec['transmitted']:.0f}/{m} "
-                      f"comms={rec['comms']} "
-                      f"saved={rec['comm_savings']*100:.1f}%")
-        if tc.ckpt_every and step and step % tc.ckpt_every == 0:
-            ckpt.save(f"{tc.ckpt_path}_step{step}",
-                      {"params": params},
-                      metadata={"step": step, "arch": cfg.name})
-    return params, state, history
+            step_fn = jax.jit(step_fn,
+                              donate_argnums=(0, 1) if tc.donate else ())
+            data = lm_data.batch_iterator(cfg, global_batch=tc.global_batch,
+                                          seq_len=tc.seq_len,
+                                          num_workers=workers_for_data,
+                                          seed=tc.seed)
+        history = []
+        t0 = time.time()
+        for step in range(tc.steps):
+            with profile.step("train/step", step):
+                with profile.annotate("train/data"):
+                    batch = next(data)
+                with profile.annotate("train/dispatch"):
+                    params, state, metrics = step_fn(params, state, batch)
+                if step % tc.log_every == 0 or step == tc.steps - 1:
+                    with profile.annotate("train/log"):
+                        rec = {k: float(v) for k, v in metrics.items()}
+                        rec.update(step=step,
+                                   comms=int(state.comm.total_uplinks),
+                                   comm_savings=float(
+                                       state.comm.savings_vs_dense()),
+                                   wall_s=round(time.time() - t0, 1))
+                        history.append(rec)
+                        if verbose:
+                            print(f"step {step:5d} loss={rec['loss']:.4f} "
+                                  f"tx={rec['transmitted']:.0f}/{m} "
+                                  f"comms={rec['comms']} "
+                                  f"saved={rec['comm_savings']*100:.1f}%")
+                if tc.ckpt_every and step and step % tc.ckpt_every == 0:
+                    ckpt.save(f"{tc.ckpt_path}_step{step}",
+                              {"params": params},
+                              metadata={"step": step, "arch": cfg.name})
+        return params, state, history

@@ -441,11 +441,6 @@ def test_annotate_and_named_scope_run(linreg, task32):
     with obs.annotate("test/span"):
         x = jnp.ones(3) + 1
     assert float(x.sum()) == 6.0
-
-    @obs.annotate_fn()
-    def f(v):
-        return v * 2
-    assert float(f(jnp.float32(2.0))) == 4.0
     # named_scope shows up in the composed step's HLO metadata
     o = opt.make("chb", linreg.alpha_paper, M)
     state = o.init(task32.init_params)
@@ -454,13 +449,6 @@ def test_annotate_and_named_scope_run(linreg, task32):
     hlo = jax.jit(lambda s, p, g: o.step(s, p, g)).lower(
         state, task32.init_params, grads).compile().as_text()
     assert "chb_step[reference]" in hlo
-
-
-def test_profiler_trace_capture(tmp_path):
-    with obs.trace(str(tmp_path / "prof")):
-        jnp.arange(8).sum().block_until_ready()
-    # capture must not have failed the block; directory may or may not
-    # contain events depending on backend support
 
 
 # ============================================================= hlo_report
