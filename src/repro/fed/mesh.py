@@ -158,6 +158,80 @@ def make_server_round(opt, mesh, quorum: float):
     return server_round
 
 
+def make_shard_round(opt, task: FedTask, scenario: MeshScenario, *,
+                     collect_metrics: bool = False):
+    """The client half of a round for one shard of ``opt.num_workers``.
+
+    ``shard_round(state, params, data, ids, comp_s, compw_s, round_idx)``
+    computes the shard's gradients, draws, ``shard_step`` and losses, and
+    returns ``(new_state, stacked_row, attempted, wall_local[, bag])``:
+    ``stacked_row`` is the shard's ``(1, ...)`` row of the fold's
+    ``(partial_agg, loss_sum, n_part, n_att, n_del, comp_j)``. ``state``
+    comes from ``opt.shard_init``, so where ``opt.bank_tiles`` it holds
+    its bank as kernel tiles, and the round keeps it so.
+    """
+    m_local = opt.num_workers
+    part_p, loss_p = scenario.participation, scenario.loss_prob
+    sync_draws, seed = scenario.sync_draws, scenario.seed
+
+    @draw_exact
+    def shard_round(state, params, data, ids, comp_s, compw_s, round_idx):
+        # the contiguous-block vmap: bit-stable under resplitting the
+        # leading axis (the only regrouping sharding performs) and
+        # identical to simulator.run's batching — see module docstring
+        # repro-lint: disable=vmap-in-draw-exact -- contiguous-block
+        # vmap is the anchor-(a) batching; lax.map would break
+        # bit-identity with simulator.run's vmapped grads
+        grads = jax.vmap(task.grad_fn, in_axes=(None, 0))(params, data)
+        if sync_draws:
+            participate = channel_mask = None
+        else:
+            rkey = jax.random.fold_in(jax.random.PRNGKey(seed), round_idx)
+
+            def draws(cid):
+                ck = jax.random.fold_in(rkey, cid)
+                return (jax.random.uniform(jax.random.fold_in(ck, 0)),
+                        jax.random.uniform(jax.random.fold_in(ck, 1)))
+
+            # repro-lint: disable=vmap-in-draw-exact -- each lane's
+            # draw is keyed by (seed, round, absolute client id)
+            # alone, so batching cannot regroup or leak across lanes
+            u_part, u_drop = jax.vmap(draws)(ids)
+            participate = (u_part < part_p).astype(jnp.float32)
+            channel_mask = (u_drop >= loss_p).astype(jnp.float32)
+        new_state, partial_agg, st = opt.shard_step(
+            state, params, grads, worker_ids=ids, participate=participate,
+            channel_mask=channel_mask)
+        # repro-lint: disable=vmap-in-draw-exact -- same
+        # contiguous-block batching as the grads; the per-shard sum
+        # is the psum partial
+        losses = jax.vmap(task.loss_fn, in_axes=(None, 0))(params, data)
+        loss_part = jnp.sum(losses)
+        if participate is None:
+            n_part = jnp.asarray(m_local, jnp.int32)
+            comp_active = comp_s
+        else:
+            n_part = jnp.sum(participate.astype(jnp.int32))
+            comp_active = jnp.where(participate != 0, comp_s, 0.0)
+        n_att = jnp.sum(st.attempted.astype(jnp.int32))
+        n_del = jnp.sum(st.delivered.astype(jnp.int32))
+        wall_local = jnp.max(comp_active) if m_local else \
+            jnp.zeros((), jnp.float32)
+        comp_j = jnp.sum(comp_active * compw_s)
+        partials = (partial_agg, loss_part, n_part, n_att, n_del, comp_j)
+        stacked_row = jax.tree_util.tree_map(lambda v: v[None], partials)
+        out = (new_state, stacked_row, st.attempted, wall_local)
+        if collect_metrics:
+            from ..obs.metrics import step_metrics
+            bag = step_metrics(opt, new_state, StepStats(
+                mask=st.mask, delta_sq=st.delta_sq, step_sq=st.step_sq,
+                agg_grad_sqnorm=tree_sqnorm(partial_agg)))
+            out = out + (bag,)
+        return out
+
+    return shard_round
+
+
 def run_mesh(cfg, task: FedTask, num_rounds: int, *,
              mesh=None,
              scenario: Optional[MeshScenario] = None,
@@ -217,22 +291,26 @@ def run_mesh(cfg, task: FedTask, num_rounds: int, *,
       A ``MeshHistory``.
     """
     with profile.annotate("run_mesh"):
-        with profile.annotate("run_mesh/setup"):
-            opt = as_optimizer(cfg)
-            if getattr(opt, "censor", None) is None or \
-                    getattr(opt, "server", None) is None:
-                raise TypeError(
-                    "run_mesh drives the censor/transport stages through "
-                    "shard_step, so it needs a ComposedOptimizer (or an "
-                    "optimizer exposing the stage attributes), not "
-                    f"{type(opt).__name__}")
-            if opt.granularity != "global":
-                raise NotImplementedError(
-                    "run_mesh supports granularity='global'")
-            if isinstance(opt.censor, AdaptiveCensor):
-                raise NotImplementedError(
-                    "run_mesh rejects adaptive censoring (cohort-wide EMA is "
-                    "ill-defined under partial participation; see fed_sweep)")
+        opt = as_optimizer(cfg)
+        if getattr(opt, "censor", None) is None or \
+                getattr(opt, "server", None) is None:
+            raise TypeError(
+                "run_mesh drives the censor/transport stages through "
+                "shard_step, so it needs a ComposedOptimizer (or an "
+                "optimizer exposing the stage attributes), not "
+                f"{type(opt).__name__}")
+        if opt.granularity != "global":
+            raise NotImplementedError(
+                "run_mesh supports granularity='global'")
+        if isinstance(opt.censor, AdaptiveCensor):
+            raise NotImplementedError(
+                "run_mesh rejects adaptive censoring (cohort-wide EMA is "
+                "ill-defined under partial participation; see fed_sweep)")
+        # the pallas dense route keeps each shard's bank as the kernels'
+        # tiles from set-up on (ComposedOptimizer.bank_tiles / shard_init)
+        tiled_leaves = len(jax.tree_util.tree_leaves(task.init_params)) \
+            if opt.bank_tiles else 0
+        with profile.annotate("run_mesh/setup", tiled_leaves=tiled_leaves):
             scenario = scenario if scenario is not None else MeshScenario()
             if isinstance(population, Population):
                 population = population.as_vector()
@@ -273,73 +351,8 @@ def run_mesh(cfg, task: FedTask, num_rounds: int, *,
                 compw_blocks.append(jax.device_put(_block(compw, i), dev))
 
             opt_local = dataclasses.replace(opt, num_workers=m_local)
-            part_p, loss_p = scenario.participation, scenario.loss_prob
-            sync_draws, seed = scenario.sync_draws, scenario.seed
-
-            # ----------------------------------------- shard round program
-            @draw_exact
-            def shard_round(state, params, data, ids, comp_s, compw_s,
-                            round_idx):
-                # the contiguous-block vmap: bit-stable under resplitting
-                # the leading axis (the only regrouping sharding performs)
-                # and identical to simulator.run's batching — see module
-                # docstring
-                # repro-lint: disable=vmap-in-draw-exact -- contiguous-block
-                # vmap is the anchor-(a) batching; lax.map would break
-                # bit-identity with simulator.run's vmapped grads
-                grads = jax.vmap(task.grad_fn, in_axes=(None, 0))(params,
-                                                                  data)
-                if sync_draws:
-                    participate = channel_mask = None
-                else:
-                    rkey = jax.random.fold_in(jax.random.PRNGKey(seed),
-                                              round_idx)
-
-                    def draws(cid):
-                        ck = jax.random.fold_in(rkey, cid)
-                        return (jax.random.uniform(jax.random.fold_in(ck, 0)),
-                                jax.random.uniform(jax.random.fold_in(ck, 1)))
-
-                    # repro-lint: disable=vmap-in-draw-exact -- each lane's
-                    # draw is keyed by (seed, round, absolute client id)
-                    # alone, so batching cannot regroup or leak across lanes
-                    u_part, u_drop = jax.vmap(draws)(ids)
-                    participate = (u_part < part_p).astype(jnp.float32)
-                    channel_mask = (u_drop >= loss_p).astype(jnp.float32)
-                new_state, partial_agg, st = opt_local.shard_step(
-                    state, params, grads, worker_ids=ids,
-                    participate=participate, channel_mask=channel_mask)
-                # repro-lint: disable=vmap-in-draw-exact -- same
-                # contiguous-block batching as the grads; the per-shard sum
-                # is the psum partial
-                losses = jax.vmap(task.loss_fn, in_axes=(None, 0))(params,
-                                                                   data)
-                loss_part = jnp.sum(losses)
-                if participate is None:
-                    n_part = jnp.asarray(m_local, jnp.int32)
-                    comp_active = comp_s
-                else:
-                    n_part = jnp.sum(participate.astype(jnp.int32))
-                    comp_active = jnp.where(participate != 0, comp_s, 0.0)
-                n_att = jnp.sum(st.attempted.astype(jnp.int32))
-                n_del = jnp.sum(st.delivered.astype(jnp.int32))
-                wall_local = jnp.max(comp_active) if m_local else \
-                    jnp.zeros((), jnp.float32)
-                comp_j = jnp.sum(comp_active * compw_s)
-                partials = (partial_agg, loss_part, n_part, n_att, n_del,
-                            comp_j)
-                stacked_row = jax.tree_util.tree_map(lambda v: v[None],
-                                                     partials)
-                out = (new_state, stacked_row, st.attempted, wall_local)
-                if collect_metrics:
-                    from ..obs.metrics import step_metrics
-                    bag = step_metrics(opt_local, new_state, StepStats(
-                        mask=st.mask, delta_sq=st.delta_sq,
-                        step_sq=st.step_sq,
-                        agg_grad_sqnorm=tree_sqnorm(partial_agg)))
-                    out = out + (bag,)
-                return out
-
+            shard_round = make_shard_round(opt_local, task, scenario,
+                                           collect_metrics=collect_metrics)
             donate_args = (0,) if donate else ()
             if bake_data:
                 def _baked(d, ii):
@@ -375,7 +388,7 @@ def run_mesh(cfg, task: FedTask, num_rounds: int, *,
             states = []
             for i, dev in enumerate(devices):
                 params_dev = jax.device_put(task.init_params, dev)
-                states.append(jax.jit(opt_local.init)(params_dev))
+                states.append(jax.jit(opt_local.shard_init)(params_dev))
 
             payload = opt.transport.payload_bytes(task.init_params)
             uplink_air = 0.0

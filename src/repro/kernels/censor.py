@@ -15,7 +15,9 @@ dispatches through (see ``ops.py``): ``censor_delta_sqnorm_batched`` /
 without ever materializing the delta tree) and ``censor_bank_advance`` /
 ``bank_advance`` (the fused bank advance ``ghat + mask * delta``, written
 in the arithmetic mask form so it is bit-identical to the reference jnp
-step).
+step). ``censor_delta_sqnorm_tiles`` / ``censor_bank_advance_tiles`` are
+their cores on operands already in tile form, for a bank kept tiled
+between calls (``fed.run_mesh``'s pallas dense route).
 
 Tiles are (block_rows, 128) VMEM blocks — ``block_rows=256`` by default,
 shrunk to the tensor's own row count for small tensors (``common.tile_rows``).
@@ -45,8 +47,9 @@ from .common import (_LANES, _pad_to_2d, _pad_to_3d, block_for,
 
 __all__ = [
     "censor_delta_sqnorm", "censor_select",
-    "censor_delta_sqnorm_batched", "sqnorm_batched",
-    "censor_bank_advance", "bank_advance",
+    "censor_delta_sqnorm_batched", "censor_delta_sqnorm_tiles",
+    "sqnorm_batched", "censor_bank_advance", "censor_bank_advance_tiles",
+    "bank_advance",
 ]
 
 
@@ -141,8 +144,19 @@ def censor_delta_sqnorm_batched(g: jax.Array, ghat: jax.Array, *,
     m = g.shape[0]
     if g.size == 0:
         return jnp.zeros((m,), jnp.float32)
-    g3 = _pad_to_3d(g, block_rows)
-    h3 = _pad_to_3d(ghat, block_rows)
+    return censor_delta_sqnorm_tiles(
+        _pad_to_3d(g, block_rows), _pad_to_3d(ghat, block_rows),
+        block_rows=block_rows, interpret=interpret)
+
+
+def censor_delta_sqnorm_tiles(g3: jax.Array, h3: jax.Array, *,
+                              block_rows: int = 256,
+                              interpret: bool | None = None) -> jax.Array:
+    """:func:`censor_delta_sqnorm_batched` on operands already tiled as
+    ``_pad_to_3d`` tiles them: ``(M, R, 128)``, zero-padded. Padding adds
+    nothing to a tile's partial, so the result is the padded entry's."""
+    assert g3.shape == h3.shape
+    m = g3.shape[0]
     block = block_for(g3, block_rows)
     nr = g3.shape[1] // block
     partials = pl.pallas_call(
@@ -215,10 +229,25 @@ def censor_bank_advance(g: jax.Array, ghat: jax.Array, mask: jax.Array, *,
     assert g.shape == ghat.shape and mask.shape == (g.shape[0],)
     if ghat.size == 0:
         return ghat
-    shape, dtype = ghat.shape, ghat.dtype
+    shape = ghat.shape
     m = g.shape[0]
-    g3 = _pad_to_3d(g, block_rows)
-    h3 = _pad_to_3d(ghat, block_rows)
+    out = censor_bank_advance_tiles(
+        _pad_to_3d(g, block_rows), _pad_to_3d(ghat, block_rows), mask,
+        block_rows=block_rows, interpret=interpret)
+    n = math.prod(shape[1:])
+    return out.reshape(m, -1)[:, :n].reshape(shape)
+
+
+def censor_bank_advance_tiles(g3: jax.Array, h3: jax.Array,
+                              mask: jax.Array, *, block_rows: int = 256,
+                              in_place: bool = False,
+                              interpret: bool | None = None) -> jax.Array:
+    """:func:`censor_bank_advance` on ``(M, R, 128)`` tiles, returning
+    the advanced bank as tiles of the same shape (its zero padding stays
+    zero). ``in_place`` aliases the output to ``h3``, so a bank donated
+    to the enclosing program advances in its own buffer."""
+    assert g3.shape == h3.shape and mask.shape == (g3.shape[0],)
+    m = g3.shape[0]
     mk = worker_scalars(mask)
     block = block_for(g3, block_rows)
     nr = g3.shape[1] // block
@@ -231,12 +260,11 @@ def censor_bank_advance(g: jax.Array, ghat: jax.Array, mask: jax.Array, *,
             pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(h3.shape, dtype),
+        out_shape=jax.ShapeDtypeStruct(h3.shape, h3.dtype),
+        input_output_aliases={2: 0} if in_place else {},
         interpret=resolve_interpret(interpret),
     )(mk, g3, h3)
-    out = log_traffic("censor_bank_advance", (mk, g3, h3), out)
-    n = math.prod(shape[1:])
-    return out.reshape(m, -1)[:, :n].reshape(shape)
+    return log_traffic("censor_bank_advance", (mk, g3, h3), out)
 
 
 def _bank_advance_kernel(m_ref, q_ref, h_ref, out_ref):

@@ -62,7 +62,7 @@ def _pad_to_2d(x: jax.Array, block_rows: int) -> jax.Array:
     return jnp.pad(flat, (0, r * _LANES - flat.shape[0])).reshape(r, _LANES)
 
 
-def _pad_to_3d(x: jax.Array, block_rows: int) -> jax.Array:
+def _pad_to_3d(x: jax.Array, block_rows: int = 256) -> jax.Array:
     """(M, ...) leaf to zero-padded (M, R, 128), each worker slice padded
     exactly as ``_pad_to_2d`` pads the slice alone."""
     m = x.shape[0]
@@ -70,6 +70,12 @@ def _pad_to_3d(x: jax.Array, block_rows: int) -> jax.Array:
     r, _ = tile_rows(flat.shape[1], block_rows)
     return jnp.pad(flat, ((0, 0), (0, r * _LANES - flat.shape[1]))
                    ).reshape(m, r, _LANES)
+
+
+def untile(x2d: jax.Array, shape) -> jax.Array:
+    """One worker's ``(R, 128)`` tiles back to a leaf of ``shape``,
+    dropping the zero padding ``_pad_to_2d`` added."""
+    return x2d.reshape(-1)[:math.prod(shape)].reshape(shape)
 
 
 def block_for(x2d: jax.Array, block_rows: int) -> int:

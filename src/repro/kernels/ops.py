@@ -78,7 +78,8 @@ def _dispatch(fn):
 
 # ----------------------------------------------------- tree-level dispatch
 @_dispatch
-def tree_delta_sqnorms(grads, bank, *, block_rows: int = 256,
+def tree_delta_sqnorms(grads, bank, *, tiles: bool = False,
+                       block_rows: int = 256,
                        interpret: bool | None = None) -> jax.Array:
     """(M,) per-worker ||g_m - ghat_m||^2 over a whole pytree.
 
@@ -88,14 +89,16 @@ def tree_delta_sqnorms(grads, bank, *, block_rows: int = 256,
     *within* a leaf the tiled partial sums regroup the float additions,
     so values agree with the reference reduction to ulps, not bits (a
     censor decision landing exactly on the eq.-(8) threshold could
-    therefore differ — see ``docs/kernels.md``).
+    therefore differ — see ``docs/kernels.md``). With ``tiles`` both
+    trees hold ``(M, R, 128)`` tiles already (``common._pad_to_3d``).
     """
+    leaf = censor.censor_delta_sqnorm_tiles if tiles else \
+        censor.censor_delta_sqnorm_batched
     leaves_g = jax.tree_util.tree_leaves(grads)
     leaves_h = jax.tree_util.tree_leaves(bank)
     acc = jnp.zeros((leaves_h[0].shape[0],), jnp.float32)
     for g, h in zip(leaves_g, leaves_h):
-        acc = acc + censor.censor_delta_sqnorm_batched(
-            g, h, block_rows=block_rows, interpret=interpret)
+        acc = acc + leaf(g, h, block_rows=block_rows, interpret=interpret)
     return acc
 
 
@@ -129,9 +132,20 @@ def tree_sqnorm_row(pending_row, *, block_rows: int = 256,
 
 
 @_dispatch
-def tree_censor_bank_advance(grads, bank, mask, *, block_rows: int = 256,
+def tree_censor_bank_advance(grads, bank, mask, *, tiles: bool = False,
+                             block_rows: int = 256,
                              interpret: bool | None = None):
-    """Fused censor-select bank advance: ``ghat + mask * (g - ghat)``."""
+    """Fused censor-select bank advance: ``ghat + mask * (g - ghat)``.
+
+    With ``tiles`` both trees hold ``(M, R, 128)`` tiles and each leaf
+    advances in its own buffer (the bank tile is aliased to the result).
+    """
+    if tiles:
+        return jax.tree_util.tree_map(
+            lambda g, h: censor.censor_bank_advance_tiles(
+                g, h, mask, block_rows=block_rows, in_place=True,
+                interpret=interpret),
+            grads, bank)
     return jax.tree_util.tree_map(
         lambda g, h: censor.censor_bank_advance(
             g, h, mask, block_rows=block_rows, interpret=interpret),

@@ -20,6 +20,7 @@ from ..core.accounting import CommStats
 from ..core.censoring import delta_sqnorms, step_sqnorm
 from ..core.util import tree_sqnorm, tree_stack_zeros, tree_sum_leading
 from ..kernels import censor as kernel_censor
+from ..kernels import common as kernel_common
 from ..kernels import fused_step as kernel_fused
 from ..kernels import ops as kernel_ops
 from .api import OptState, ShardStepStats, StepStats, static_pos
@@ -212,6 +213,29 @@ class ComposedOptimizer:
             comm=CommStats.init(self.num_workers),
             censor=self.censor.init(self.num_workers),
         )
+
+    @property
+    def bank_tiles(self) -> bool:
+        """Whether ``shard_step`` can hold the bank as the kernels' tiles.
+
+        True for the pallas backend's stateless (dense) transport at
+        global granularity: there only the two staged bank kernels and
+        the eq.-(5) worker-sum read the bank, so it can stay in the
+        ``(M, R, 128)`` form those kernels take (``shard_init``) instead
+        of being padded into it and sliced back out every round.
+        """
+        return (self.backend == "pallas" and not self.transport.stateful
+                and self.granularity == "global")
+
+    def shard_init(self, params) -> OptState:
+        """The shard-local state ``shard_step`` takes: ``init``, with the
+        bank leaves as ``(M, R, 128)`` kernel tiles where ``bank_tiles``.
+        """
+        state = self.init(params)
+        if not self.bank_tiles:
+            return state
+        return state._replace(ghat=jax.tree_util.tree_map(
+            kernel_common._pad_to_3d, state.ghat))
 
     def metrics(self, state: OptState, stats: StepStats):
         """Per-round ``repro.obs`` MetricBag for a completed step.
@@ -406,8 +430,9 @@ class ComposedOptimizer:
         ``step``'s agg + apply).
 
         Args:
-          state: SHARD-LOCAL state (``(M_local, ...)`` bank rows, the
-            shard's own CommStats; replicated censor state).
+          state: SHARD-LOCAL state from ``shard_init`` (``(M_local, ...)``
+            bank rows, as kernel tiles where ``bank_tiles``; the shard's
+            own CommStats; replicated censor state).
           params / worker_grads: theta^k (replicated) and the shard's
             ``(M_local, ...)`` stacked gradients.
           worker_ids: the shard's absolute global client ids — draw-keyed
@@ -469,9 +494,15 @@ class ComposedOptimizer:
         half of a sharded round runs after the cross-shard fold — so this
         path always takes the staged kernels (sqnorm sweeps, fused
         encode+EF, fused bank advance), matching ``_step_pallas`` with
-        ``force_staged()`` minus the server apply."""
+        ``force_staged()`` minus the server apply. With ``bank_tiles`` the
+        gradient is tiled once, both kernels run on tiles, and the bank
+        advances in place."""
         quantized = self.transport.stateful
+        bank_tiles = self.bank_tiles
         pending = None
+        if bank_tiles:
+            worker_grads = jax.tree_util.tree_map(kernel_common._pad_to_3d,
+                                                  worker_grads)
         if quantized:
             delta = jax.tree_util.tree_map(
                 lambda g, h: g.astype(h.dtype) - h,
@@ -479,7 +510,8 @@ class ComposedOptimizer:
             pending = self.transport.prepare(delta, state.err)
             dsq = kernel_ops.tree_sqnorms(pending)
         else:
-            dsq = kernel_ops.tree_delta_sqnorms(worker_grads, state.ghat)
+            dsq = kernel_ops.tree_delta_sqnorms(worker_grads, state.ghat,
+                                                tiles=bank_tiles)
         ssq = step_sqnorm(params, state.prev_params)
         mask, new_censor = self._decide(state.censor, dsq, ssq, worker_ids)
         attempted_mask, delivered_mask = _gate(mask, participate,
@@ -493,8 +525,12 @@ class ComposedOptimizer:
         else:
             new_err = state.err
             new_ghat = kernel_ops.tree_censor_bank_advance(
-                worker_grads, state.ghat, delivered_mask)
+                worker_grads, state.ghat, delivered_mask, tiles=bank_tiles)
         partial = tree_sum_leading(new_ghat)
+        if bank_tiles:      # only the (R, 128) sum leaves the tile form
+            partial = jax.tree_util.tree_map(
+                lambda p, t: kernel_common.untile(p, t.shape),
+                partial, params)
 
         stats = ShardStepStats(mask=mask, attempted=attempted_mask,
                                delivered=delivered_mask, delta_sq=dsq,

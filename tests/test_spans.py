@@ -157,6 +157,22 @@ def test_run_mesh_sync_reads_two_arrays_per_shard(shards):
         [7 + 2 * shards] * ROUNDS
 
 
+@pytest.mark.parametrize("route,want", [
+    ({"backend": "pallas"}, 2), ({}, 0),
+    ({"backend": "pallas", "quantize": "int8"}, 0)],
+    ids=["pallas-dense", "reference", "pallas-int8"])
+def test_run_mesh_setup_counts_tiled_leaves(route, want):
+    """The set-up span says how many bank leaves each shard holds as
+    kernel tiles: both of a W-and-b task's on the pallas dense route,
+    none where the bank stays untiled."""
+    from test_bank_tiles import mlr_task
+    task = mlr_task(m=4)
+    o = opt.make("chb", 0.5, 4, **route)
+    _, spans = _traced(lambda: run_mesh(o, task, 1))
+    setup, = _named(spans, "run_mesh/setup")
+    assert setup.stats["tiled_leaves"] == want
+
+
 def test_run_mesh_same_with_profiler_on_and_off(mesh_run, mesh_traced):
     traced, _ = mesh_traced
     plain = mesh_run()

@@ -11,6 +11,11 @@ hands them:
   * ``fleet``: one ``fed.run_mesh`` shard of M=10^5 clients x d=16
     (``ComposedOptimizer.shard_step``'s staged ``grid=(M, rows)`` kernels).
 
+One ``fed.run_mesh`` shard round also compiles whole, at the shapes of
+the benchmark's ``emnist62-mlr`` population (M=3400 writers, W 784 x 62,
+b 62), to read from its HLO that the client bank stays in the kernels'
+tiles between rounds.
+
 The fused megakernels hold the whole worker axis in one VMEM block, so
 they compile at the ``lm`` width only: ``shard_step`` never calls them.
 The server half of a ``fed.run_mesh`` round compiles over all four chips
@@ -21,7 +26,9 @@ A new kernel joins this file. The topology is described inside a fixture,
 never at import: only one process may load the TPU compiler library, and
 the suite runs under several xdist workers.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -31,7 +38,8 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec,
                           SingleDeviceSharding)
 
 from repro import opt
-from repro.fed.mesh import make_server_round
+from repro.core.simulator import FedTask
+from repro.fed.mesh import MeshScenario, make_server_round, make_shard_round
 from repro.kernels import (censor, common, fused_step, hb_update, lowrank_ef,
                            quantize_ef, topk_pack)
 
@@ -182,3 +190,93 @@ def test_fed_mesh_server_round_compiles_on_four_chips(topo, monkeypatch):
                                                 theta).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+
+
+def _mlr_task(params, images: int):
+    """emnist62-mlr's task: each writer's summed cross-entropy over its
+    padded samples, scaled by the population's sample count."""
+    scale = 1.0 / images
+
+    def logits(p, d):
+        return d["x"] @ p["W"] + p["b"]
+
+    def valid(d):
+        return (jnp.arange(d["y"].shape[0]) < d["n"]).astype(jnp.float32)
+
+    def grad_fn(p, d):
+        z = jax.nn.softmax(logits(p, d), axis=-1)
+        onehot = jax.nn.one_hot(d["y"], z.shape[-1], dtype=z.dtype)
+        r = (z - onehot) * (valid(d) * scale)[:, None]
+        return {"W": d["x"].T @ r, "b": jnp.sum(r, axis=0)}
+
+    def loss_fn(p, d):
+        z = logits(p, d)
+        onehot = jax.nn.one_hot(d["y"], z.shape[-1], dtype=z.dtype)
+        per = jax.nn.logsumexp(z, axis=-1) - jnp.sum(z * onehot, axis=-1)
+        return jnp.sum(per * valid(d)) * scale
+
+    return FedTask(init_params=params, grad_fn=grad_fn, loss_fn=loss_fn,
+                   worker_data=None)
+
+
+_SHAPE = re.compile(r"\b[a-z]+[0-9]*\[([0-9,]*)\]")
+_INSTR = re.compile(r"\s*(?:ROOT\s+)?%[\w.\-]+\s*=\s*.*?\s([a-z][\w\-]*)\(")
+
+
+def _bank_sized(text: str, m: int, width: int) -> list:
+    """Opcodes of the instructions, fused ones included, whose result or
+    operand holds ``m`` rows of at least ``width`` elements."""
+    ops = []
+    for line in text.splitlines():
+        body = line.split(", metadata=")[0]
+        found = _INSTR.match(body)
+        if found is None:
+            continue
+        for dims in _SHAPE.findall(body):
+            d = [int(x) for x in dims.split(",") if x]
+            if len(d) > 1 and d[0] == m and math.prod(d[1:]) >= width:
+                ops.append(found.group(1))
+                break
+    return ops
+
+
+def test_fed_mesh_shard_round_keeps_bank_in_tiles(one_chip, monkeypatch):
+    """The one-chip shard round at emnist62-mlr's shapes, its bank held as
+    tiles and donated as the benchmark runs it: the only Pallas calls are
+    the round's kernels, the bank advances in its own buffer, and of the
+    bank-sized relayouts only the fresh gradient's tiling is left."""
+    monkeypatch.setattr(common, "interpret_default", lambda: False)
+    m, pixels, classes, samples = 3400, 784, 62, 512
+    params = {"W": _shape(one_chip, (pixels, classes)),
+              "b": _shape(one_chip, (classes,))}
+    o = opt.make("chb", 0.05, m, beta=0.4, eps1_scale=0.5, backend="pallas")
+    assert o.bank_tiles
+    state = jax.tree_util.tree_map(
+        lambda x: _shape(one_chip, x.shape, x.dtype),
+        jax.eval_shape(o.shard_init, params))
+    assert state.ghat["W"].shape == (m, 512, 128)
+    data = {"x": _shape(one_chip, (m, samples, pixels)),
+            "y": _shape(one_chip, (m, samples), jnp.int32),
+            "n": _shape(one_chip, (m,), jnp.int32)}
+    fn = make_shard_round(o, _mlr_task(params, 671_585), MeshScenario())
+    text = jax.jit(fn, donate_argnums=(0,)).lower(
+        state, params, data, _shape(one_chip, (m,), jnp.uint32),
+        _shape(one_chip, (m,)), _shape(one_chip, (m,)),
+        _shape(one_chip, (), jnp.int32)).compile().as_text()
+
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    scopes = [re.search(r"kernels/(\w+)/pallas_call", line)
+              for line in kernels]
+    assert kernels and all(scopes), kernels
+    assert {s.group(1) for s in scopes} <= {
+        "tree_delta_sqnorms", "tree_censor_bank_advance", "tree_hb_update"}
+    advance = [line for line, s in zip(kernels, scopes)
+               if s.group(1) == "tree_censor_bank_advance"]
+    assert len(advance) == 2       # W and b
+    assert all("output_to_operand_aliasing={{}: (2," in line
+               for line in advance), advance
+    relayouts = [op for op in _bank_sized(text, m, pixels * classes)
+                 if op in ("pad", "copy")]
+    assert relayouts.count("pad") <= 1 and relayouts.count("copy") <= 2, \
+        relayouts
