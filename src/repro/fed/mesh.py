@@ -42,10 +42,16 @@ regrouping sharding performs), which experiment-validated bitwise at
 K in {1, 2, 4, 8}, while a per-client ``lax.map`` is NOT bit-identical
 to the vmapped ``simulator.run`` grads and would break anchor (a). The
 inline lint suppressions below carry that argument.
+
+Where participation is drawn and small (``cohort_capacity``), a shard
+round draws first and computes only the drawn writers, gathered in blocks
+of C rows; draws, masks, counts and bytes stay those of the round that
+steps every writer (docs/fed_scaling.md, "Cohort rounds").
 """
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, NamedTuple, Optional
 
 import jax
@@ -158,6 +164,53 @@ def make_server_round(opt, mesh, quorum: float):
     return server_round
 
 
+def _gather_rows(x, rows):
+    """``x[rows]`` along the writer axis (a row past the end reads the
+    last), as one slice per row joined together.
+
+    Written for the TPU compiler, at the population's full size: its
+    gather first cuts every writer's row into lane-aligned pieces of the
+    whole array, and a loop writing rows into a buffer re-lays the whole
+    array out; a join of row slices does neither. Float rows go through
+    as their bits, behind a barrier: a float join lets the compiler round
+    every writer's data for the gradient's matmul once the join sits in
+    the block loop, instead of the C rows it needs.
+    """
+    if not jnp.issubdtype(x.dtype, jnp.floating):
+        return jnp.take(x, rows, axis=0, mode="clip")
+    # a start past the end clamps to the last row, as every slice's does
+    picked = [jax.lax.dynamic_slice_in_dim(x, r, 1,
+                                           allow_negative_indices=False)
+              for r in rows]
+    word = jnp.dtype(f"uint{8 * x.dtype.itemsize}")
+    joined = jnp.concatenate([jax.lax.bitcast_convert_type(p, word)
+                              for p in picked])
+    return jax.lax.bitcast_convert_type(
+        jax.lax.optimization_barrier(joined), x.dtype)
+
+
+def cohort_capacity(m_local: int, scenario: MeshScenario, censor, *,
+                    collect_metrics: bool = False) -> int:
+    """Rows per block of a shard round's gathered cohort, or 0 for the
+    round that steps every writer.
+
+    ``C = ceil8(m p + 6 sqrt(m p (1 - p)))`` for ``m_local = m`` writers
+    drawn at participation ``p``: six standard deviations over the mean of
+    the Binomial(m, p) cohort, so a draw past it (exact still, by a second
+    block) is rare. The gathered round runs only where it pays and stays
+    exact: where draws exist (not ``sync_draws``), ``C <= m_local / 2``,
+    the censor decides per client (``supports_event_runtime``), and no
+    metric bag is collected (its entries read every writer's norms).
+    """
+    if scenario.sync_draws or collect_metrics or \
+            not getattr(censor, "supports_event_runtime", False):
+        return 0
+    p = scenario.participation
+    mean = m_local * p
+    c = 8 * math.ceil((mean + 6.0 * math.sqrt(mean * (1.0 - p))) / 8.0)
+    return c if 2 * c <= m_local else 0
+
+
 def make_shard_round(opt, task: FedTask, scenario: MeshScenario, *,
                      collect_metrics: bool = False):
     """The client half of a round for one shard of ``opt.num_workers``.
@@ -169,20 +222,28 @@ def make_shard_round(opt, task: FedTask, scenario: MeshScenario, *,
     ``(partial_agg, loss_sum, n_part, n_att, n_del, comp_j)``. ``state``
     comes from ``opt.shard_init``, so where ``opt.bank_tiles`` it holds
     its bank as kernel tiles, and the round keeps it so.
+
+    Where ``cohort_capacity`` gives C > 0 the round draws first and
+    computes gradients for the drawn writers only: ``shard_step(capacity=
+    C)`` gathers them in blocks of C rows and scatters their state back.
+    The loss that makes the objective still covers every writer.
     """
     m_local = opt.num_workers
     part_p, loss_p = scenario.participation, scenario.loss_prob
     sync_draws, seed = scenario.sync_draws, scenario.seed
+    capacity = cohort_capacity(m_local, scenario, opt.censor,
+                               collect_metrics=collect_metrics)
 
     @draw_exact
     def shard_round(state, params, data, ids, comp_s, compw_s, round_idx):
-        # the contiguous-block vmap: bit-stable under resplitting the
-        # leading axis (the only regrouping sharding performs) and
-        # identical to simulator.run's batching — see module docstring
-        # repro-lint: disable=vmap-in-draw-exact -- contiguous-block
-        # vmap is the anchor-(a) batching; lax.map would break
-        # bit-identity with simulator.run's vmapped grads
-        grads = jax.vmap(task.grad_fn, in_axes=(None, 0))(params, data)
+        if not capacity:
+            # the contiguous-block vmap: bit-stable under resplitting the
+            # leading axis (the only regrouping sharding performs) and
+            # identical to simulator.run's batching — see module docstring
+            # repro-lint: disable=vmap-in-draw-exact -- contiguous-block
+            # vmap is the anchor-(a) batching; lax.map would break
+            # bit-identity with simulator.run's vmapped grads
+            grads = jax.vmap(task.grad_fn, in_axes=(None, 0))(params, data)
         if sync_draws:
             participate = channel_mask = None
         else:
@@ -199,9 +260,19 @@ def make_shard_round(opt, task: FedTask, scenario: MeshScenario, *,
             u_part, u_drop = jax.vmap(draws)(ids)
             participate = (u_part < part_p).astype(jnp.float32)
             channel_mask = (u_drop >= loss_p).astype(jnp.float32)
+        if capacity:
+            def grads(rows):
+                block = jax.tree_util.tree_map(
+                    lambda x: _gather_rows(x, rows), data)
+                # repro-lint: disable=vmap-in-draw-exact -- the drawn
+                # cohort's rows: each writer's gradient reads its own row
+                # alone, and tests/test_cohort_gather.py holds masks and
+                # counts exact to the contiguous-block round
+                return jax.vmap(task.grad_fn, in_axes=(None, 0))(params,
+                                                                 block)
         new_state, partial_agg, st = opt.shard_step(
             state, params, grads, worker_ids=ids, participate=participate,
-            channel_mask=channel_mask)
+            channel_mask=channel_mask, capacity=capacity)
         # repro-lint: disable=vmap-in-draw-exact -- same
         # contiguous-block batching as the grads; the per-shard sum
         # is the psum partial
@@ -310,24 +381,27 @@ def run_mesh(cfg, task: FedTask, num_rounds: int, *,
         # tiles from set-up on (ComposedOptimizer.bank_tiles / shard_init)
         tiled_leaves = len(jax.tree_util.tree_leaves(task.init_params)) \
             if opt.bank_tiles else 0
-        with profile.annotate("run_mesh/setup", tiled_leaves=tiled_leaves):
-            scenario = scenario if scenario is not None else MeshScenario()
+        scenario = scenario if scenario is not None else MeshScenario()
+        m = jax.tree_util.tree_leaves(task.worker_data)[0].shape[0]
+        if opt.num_workers != m:
+            raise ValueError(
+                f"cfg.num_workers={opt.num_workers} != task M={m}")
+        mesh = mesh if mesh is not None else make_client_mesh(1)
+        m_local = client_shard_sizes(m, mesh)
+        # rows of a shard round's gathered cohort (0: every writer stepped)
+        cohort_rows = cohort_capacity(m_local, scenario, opt.censor,
+                                      collect_metrics=collect_metrics)
+        with profile.annotate("run_mesh/setup", tiled_leaves=tiled_leaves,
+                              cohort_rows=cohort_rows):
             if isinstance(population, Population):
                 population = population.as_vector()
             channel = channel if channel is not None else \
                 ChannelConfig.ideal()
             energy = energy if energy is not None else EnergyModel()
-
-            m = jax.tree_util.tree_leaves(task.worker_data)[0].shape[0]
-            if opt.num_workers != m:
-                raise ValueError(
-                    f"cfg.num_workers={opt.num_workers} != task M={m}")
             if population is not None and population.num_clients != m:
                 raise ValueError(
                     f"population has {population.num_clients} clients, "
                     f"task has {m}")
-            mesh = mesh if mesh is not None else make_client_mesh(1)
-            m_local = client_shard_sizes(m, mesh)
             devices = list(mesh.devices.flat)
             k_shards = len(devices)
 
@@ -447,7 +521,10 @@ def run_mesh(cfg, task: FedTask, num_rounds: int, *,
                                        for kk, v in o[4].items()}
                                       for o in outs]
 
-                with profile.annotate("run_mesh/account"):
+                # one shard's cohort is the round's: the blocks it took
+                chunks = {} if not cohort_rows or k_shards > 1 else {
+                    "cohort_chunks": max(1, -(-part_h // cohort_rows))}
+                with profile.annotate("run_mesh/account", **chunks):
                     objective.append(loss_h)
                     gsq_hist.append(gsq_h)
                     met_hist.append(met_h)

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .common import (_LANES, _pad_to_2d, _pad_to_3d, block_for,
+from .common import (_LANES, _pad_to_2d, _pad_to_3d, block_for, in_hbm,
                      log_traffic, resolve_interpret,
                      tile_partials_spec, worker_scalar_spec, worker_scalars)
 
@@ -150,12 +150,15 @@ def censor_delta_sqnorm_batched(g: jax.Array, ghat: jax.Array, *,
 
 
 def censor_delta_sqnorm_tiles(g3: jax.Array, h3: jax.Array, *,
-                              block_rows: int = 256,
+                              block_rows: int = 256, hbm: bool = False,
                               interpret: bool | None = None) -> jax.Array:
     """:func:`censor_delta_sqnorm_batched` on operands already tiled as
     ``_pad_to_3d`` tiles them: ``(M, R, 128)``, zero-padded. Padding adds
-    nothing to a tile's partial, so the result is the padded entry's."""
+    nothing to a tile's partial, so the result is the padded entry's.
+    ``hbm`` holds both operands in HBM (``common.in_hbm``)."""
     assert g3.shape == h3.shape
+    if hbm:
+        g3, h3 = in_hbm(g3, interpret), in_hbm(h3, interpret)
     m = g3.shape[0]
     block = block_for(g3, block_rows)
     nr = g3.shape[1] // block
@@ -240,13 +243,19 @@ def censor_bank_advance(g: jax.Array, ghat: jax.Array, mask: jax.Array, *,
 
 def censor_bank_advance_tiles(g3: jax.Array, h3: jax.Array,
                               mask: jax.Array, *, block_rows: int = 256,
-                              in_place: bool = False,
+                              in_place: bool = False, hbm: bool = False,
                               interpret: bool | None = None) -> jax.Array:
     """:func:`censor_bank_advance` on ``(M, R, 128)`` tiles, returning
     the advanced bank as tiles of the same shape (its zero padding stays
     zero). ``in_place`` aliases the output to ``h3``, so a bank donated
-    to the enclosing program advances in its own buffer."""
+    to the enclosing program advances in its own buffer. ``hbm`` holds
+    both tile operands and the result in HBM (``common.in_hbm``)."""
     assert g3.shape == h3.shape and mask.shape == (g3.shape[0],)
+    out_shape = jax.ShapeDtypeStruct(h3.shape, h3.dtype)
+    if hbm:
+        g3, h3 = in_hbm(g3, interpret), in_hbm(h3, interpret)
+        if not resolve_interpret(interpret):
+            out_shape = pltpu.HBM(h3.shape, h3.dtype)
     m = g3.shape[0]
     mk = worker_scalars(mask)
     block = block_for(g3, block_rows)
@@ -260,7 +269,7 @@ def censor_bank_advance_tiles(g3: jax.Array, h3: jax.Array,
             pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block, _LANES), lambda w, i: (w, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(h3.shape, h3.dtype),
+        out_shape=out_shape,
         input_output_aliases={2: 0} if in_place else {},
         interpret=resolve_interpret(interpret),
     )(mk, g3, h3)

@@ -41,6 +41,19 @@ def resolve_interpret(interpret) -> bool:
     return interpret_default() if interpret is None else bool(interpret)
 
 
+def in_hbm(x: jax.Array, interpret: bool | None = None) -> jax.Array:
+    """``x`` held in HBM where a compiled kernel takes it as an operand.
+
+    Left to itself the TPU compiler may stage a kernel operand of a few
+    tens of MB in VMEM, and the fusion that makes it then carries the
+    read the kernel's own pipeline would do. The interpreter has no
+    memory spaces, so there ``x`` passes as it is.
+    """
+    if resolve_interpret(interpret):
+        return x
+    return pltpu.with_memory_space_constraint(x, pltpu.HBM)
+
+
 def tile_rows(n: int, block_rows: int) -> tuple[int, int]:
     """(padded row count R, grid length nr) for ``n`` flat elements.
 

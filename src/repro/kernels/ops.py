@@ -79,7 +79,7 @@ def _dispatch(fn):
 # ----------------------------------------------------- tree-level dispatch
 @_dispatch
 def tree_delta_sqnorms(grads, bank, *, tiles: bool = False,
-                       block_rows: int = 256,
+                       hbm: bool = False, block_rows: int = 256,
                        interpret: bool | None = None) -> jax.Array:
     """(M,) per-worker ||g_m - ghat_m||^2 over a whole pytree.
 
@@ -90,10 +90,11 @@ def tree_delta_sqnorms(grads, bank, *, tiles: bool = False,
     so values agree with the reference reduction to ulps, not bits (a
     censor decision landing exactly on the eq.-(8) threshold could
     therefore differ — see ``docs/kernels.md``). With ``tiles`` both
-    trees hold ``(M, R, 128)`` tiles already (``common._pad_to_3d``).
+    trees hold ``(M, R, 128)`` tiles already (``common._pad_to_3d``), and
+    ``hbm`` holds them in HBM (``common.in_hbm``).
     """
-    leaf = censor.censor_delta_sqnorm_tiles if tiles else \
-        censor.censor_delta_sqnorm_batched
+    leaf = functools.partial(censor.censor_delta_sqnorm_tiles, hbm=hbm) \
+        if tiles else censor.censor_delta_sqnorm_batched
     leaves_g = jax.tree_util.tree_leaves(grads)
     leaves_h = jax.tree_util.tree_leaves(bank)
     acc = jnp.zeros((leaves_h[0].shape[0],), jnp.float32)
@@ -133,17 +134,18 @@ def tree_sqnorm_row(pending_row, *, block_rows: int = 256,
 
 @_dispatch
 def tree_censor_bank_advance(grads, bank, mask, *, tiles: bool = False,
-                             block_rows: int = 256,
+                             hbm: bool = False, block_rows: int = 256,
                              interpret: bool | None = None):
     """Fused censor-select bank advance: ``ghat + mask * (g - ghat)``.
 
     With ``tiles`` both trees hold ``(M, R, 128)`` tiles and each leaf
-    advances in its own buffer (the bank tile is aliased to the result).
+    advances in its own buffer (the bank tile is aliased to the result);
+    ``hbm`` holds them in HBM (``common.in_hbm``).
     """
     if tiles:
         return jax.tree_util.tree_map(
             lambda g, h: censor.censor_bank_advance_tiles(
-                g, h, mask, block_rows=block_rows, in_place=True,
+                g, h, mask, block_rows=block_rows, in_place=True, hbm=hbm,
                 interpret=interpret),
             grads, bank)
     return jax.tree_util.tree_map(

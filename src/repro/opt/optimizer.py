@@ -44,6 +44,17 @@ def _gate(mask, participate, channel_mask):
     return attempted_mask, delivered_mask
 
 
+def _worker_axis_leaves(init, params):
+    """Which leaves of the state ``init(params, k)`` builds carry the
+    worker axis: those whose shape changes with the worker count ``k``."""
+    shapes = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
+    one, two = (jax.eval_shape(lambda p, k=k: init(p, k), shapes)
+                for k in (1, 2))
+    return jax.tree_util.tree_map(lambda a, b: a.shape != b.shape, one,
+                                  two)
+
+
 @dataclasses.dataclass(frozen=True)
 class ComposedOptimizer:
     """One censor policy + one transport + one server update.
@@ -414,7 +425,8 @@ class ComposedOptimizer:
         return new_state, new_params, stats
 
     def shard_step(self, state: OptState, params, worker_grads, *,
-                   worker_ids=None, participate=None, channel_mask=None
+                   worker_ids=None, participate=None, channel_mask=None,
+                   capacity: int = 0
                    ) -> tuple[OptState, Any, ShardStepStats]:
         """The client-side half of a step, for ONE mesh shard.
 
@@ -434,7 +446,10 @@ class ComposedOptimizer:
             bank rows, as kernel tiles where ``bank_tiles``; the shard's
             own CommStats; replicated censor state).
           params / worker_grads: theta^k (replicated) and the shard's
-            ``(M_local, ...)`` stacked gradients.
+            ``(M_local, ...)`` stacked gradients — or, with ``capacity``,
+            a function from ``(capacity,)`` shard-local row indices to
+            those rows' stacked gradients (an index ``>= M_local`` marks
+            an empty slot, whose gradient is never used).
           worker_ids: the shard's absolute global client ids — draw-keyed
             censors fold these so the masks are invariant to how the
             population is split (omit for a single full-population shard).
@@ -444,23 +459,51 @@ class ComposedOptimizer:
             survived the channel. Transmissions that drop still spend
             bytes/energy (``attempted``) but never reach the bank
             (``delivered``), matching ``sweep.fed_sweep`` semantics.
+          capacity: when positive, step only the participants: they are
+            gathered in blocks of this many rows (as many blocks as the
+            draw needs, one in the usual case), and each block's gradients,
+            bank rows and transport-state rows go through the same per-row
+            stages, then back into place. Every other row's state is left
+            as it is, which is what the full step does to a non-participant
+            (``_gate`` zeroes its attempt and delivery). Needs
+            ``participate`` and a censor whose decisions are per client
+            (``supports_event_runtime``). Global state (``prev_params``,
+            censor state, the global counters) advances once, and the
+            partial is summed over the whole bank, as without it.
         Returns:
-          ``(new_state, partial_agg, ShardStepStats)``.
+          ``(new_state, partial_agg, ShardStepStats)``. With ``capacity``
+          the stats' ``mask`` and ``delta_sq`` read 0 off the cohort.
         """
         if self.granularity != "global":
             raise NotImplementedError(
                 "shard_step supports global granularity only (per_tensor "
                 "byte accounting is host-side and unsharded)")
-        if self.backend == "pallas":
-            return self._shard_step_pallas(
-                state, params, worker_grads, worker_ids=worker_ids,
-                participate=participate, channel_mask=channel_mask)
+        ssq = step_sqnorm(params, state.prev_params)
+        if capacity:
+            return self._shard_step_cohort(
+                state, params, worker_grads, ssq, capacity,
+                worker_ids=worker_ids, participate=participate,
+                channel_mask=channel_mask)
+        new_ghat, new_err, new_censor, stats = self._shard_rows(
+            state, worker_grads, ssq, worker_ids, participate, channel_mask)
+        return self._shard_finish(state, params, new_ghat, new_err,
+                                  new_censor, stats)
 
+    def _shard_rows(self, state: OptState, worker_grads, ssq, worker_ids,
+                    participate, channel_mask, *, hbm: bool = False):
+        """The per-row stages of ``shard_step`` over the rows ``state``
+        holds: eq.-(8) norms, the censor decision, the gates, encode and
+        error feedback, the bank advance. Returns ``(new_ghat, new_err,
+        new_censor, ShardStepStats)``. ``hbm`` holds the tiled kernels'
+        operands in HBM (``kernels.common.in_hbm``)."""
+        if self.backend == "pallas":
+            return self._shard_rows_pallas(state, worker_grads, ssq,
+                                           worker_ids, participate,
+                                           channel_mask, hbm=hbm)
         delta = jax.tree_util.tree_map(
             lambda g, h: g.astype(h.dtype) - h, worker_grads, state.ghat)
         pending = self.transport.prepare(delta, state.err)
         dsq = delta_sqnorms(pending)
-        ssq = step_sqnorm(params, state.prev_params)
         mask, new_censor = self._decide(state.censor, dsq, ssq, worker_ids)
         attempted_mask, delivered_mask = _gate(mask, participate,
                                                channel_mask)
@@ -471,25 +514,15 @@ class ComposedOptimizer:
         new_ghat = jax.tree_util.tree_map(
             lambda h, q: h + _bcast(delivered_mask, h) * q.astype(h.dtype),
             state.ghat, payload)
-        partial = tree_sum_leading(new_ghat)
-
         stats = ShardStepStats(mask=mask, attempted=attempted_mask,
                                delivered=delivered_mask, delta_sq=dsq,
                                step_sq=ssq)
-        new_state = OptState(
-            prev_params=params,
-            ghat=new_ghat,
-            err=new_err,
-            comm=state.comm.update(attempted_mask,
-                                   self.transport.payload_bytes(params)),
-            censor=new_censor,
-        )
-        return new_state, partial, stats
+        return new_ghat, new_err, new_censor, stats
 
-    def _shard_step_pallas(self, state: OptState, params, worker_grads, *,
-                           worker_ids=None, participate=None,
-                           channel_mask=None):
-        """Staged-kernel ``shard_step``. The megakernel is out of reach
+    def _shard_rows_pallas(self, state: OptState, worker_grads, ssq,
+                           worker_ids, participate, channel_mask, *,
+                           hbm: bool = False):
+        """Staged-kernel ``_shard_rows``. The megakernel is out of reach
         here — it fuses the eq.-(4) update into the sweep, and the server
         half of a sharded round runs after the cross-shard fold — so this
         path always takes the staged kernels (sqnorm sweeps, fused
@@ -511,8 +544,7 @@ class ComposedOptimizer:
             dsq = kernel_ops.tree_sqnorms(pending)
         else:
             dsq = kernel_ops.tree_delta_sqnorms(worker_grads, state.ghat,
-                                                tiles=bank_tiles)
-        ssq = step_sqnorm(params, state.prev_params)
+                                                tiles=bank_tiles, hbm=hbm)
         mask, new_censor = self._decide(state.censor, dsq, ssq, worker_ids)
         attempted_mask, delivered_mask = _gate(mask, participate,
                                                channel_mask)
@@ -525,25 +557,99 @@ class ComposedOptimizer:
         else:
             new_err = state.err
             new_ghat = kernel_ops.tree_censor_bank_advance(
-                worker_grads, state.ghat, delivered_mask, tiles=bank_tiles)
-        partial = tree_sum_leading(new_ghat)
-        if bank_tiles:      # only the (R, 128) sum leaves the tile form
-            partial = jax.tree_util.tree_map(
-                lambda p, t: kernel_common.untile(p, t.shape),
-                partial, params)
-
+                worker_grads, state.ghat, delivered_mask, tiles=bank_tiles,
+                hbm=hbm)
         stats = ShardStepStats(mask=mask, attempted=attempted_mask,
                                delivered=delivered_mask, delta_sq=dsq,
                                step_sq=ssq)
+        return new_ghat, new_err, new_censor, stats
+
+    def _shard_finish(self, state: OptState, params, new_ghat, new_err,
+                      new_censor, stats: ShardStepStats):
+        """The once-a-round half of ``shard_step``: the eq.-(5) partial
+        over the whole shard bank, and the new state."""
+        partial = tree_sum_leading(new_ghat)
+        if self.bank_tiles:  # only the (R, 128) sum leaves the tile form
+            partial = jax.tree_util.tree_map(
+                lambda p, t: kernel_common.untile(p, t.shape),
+                partial, params)
         new_state = OptState(
             prev_params=params,
             ghat=new_ghat,
             err=new_err,
-            comm=state.comm.update(attempted_mask,
+            comm=state.comm.update(stats.attempted,
                                    self.transport.payload_bytes(params)),
             censor=new_censor,
         )
         return new_state, partial, stats
+
+    def _shard_step_cohort(self, state: OptState, params, grad_rows, ssq,
+                           capacity: int, *, worker_ids, participate,
+                           channel_mask):
+        """``shard_step(capacity=C)``: the per-row stages on the
+        participants only, in blocks of C gathered rows.
+
+        The participants' shard-local rows, in order, are cut into blocks
+        of C; a ``while_loop`` steps one block per trip (the first always
+        runs, so a round with no participant still advances the censor
+        state). Empty slots index past the shard: their gathers clip, their
+        gates are 0 and their scatters are dropped. Each block reads the
+        round's starting censor state and ``prev_params``, as every row
+        does in the full step, and a per-client censor decides each row
+        from that row alone, so any split into blocks gives the full
+        step's decisions and rows."""
+        if participate is None:
+            raise ValueError("shard_step(capacity=...) steps the "
+                             "participants, so it needs participate")
+        if not getattr(self.censor, "supports_event_runtime", False):
+            raise ValueError(
+                f"{type(self.censor).__name__} decides over the whole "
+                "cohort, so its rows cannot be stepped apart")
+        m, c = self.num_workers, int(capacity)
+        # every block start j*C < max(n, 1) <= m keeps j*C + C <= m + C
+        order = jnp.nonzero(participate, size=m + c, fill_value=m)[0]
+        n = jnp.sum((participate != 0).astype(jnp.int32))
+        blocks = jnp.maximum(1, (n + c - 1) // c)
+        row_leaves = _worker_axis_leaves(self.transport.init, params)
+
+        def take(x, rows):
+            return jnp.take(x, rows, axis=0, mode="clip")
+
+        def put(x, rows, v):
+            return x.at[rows].set(v, mode="drop")
+
+        def trip(carry):
+            j, ghat, err, att, dlv, msk, dsq, _ = carry
+            rows = jax.lax.dynamic_slice(order, (j * c,), (c,))
+            ids = rows if worker_ids is None else take(worker_ids, rows)
+            sub = state._replace(
+                ghat=jax.tree_util.tree_map(lambda x: take(x, rows), ghat),
+                err=jax.tree_util.tree_map(
+                    lambda x, r: take(x, rows) if r else x, err, row_leaves))
+            gate = None if channel_mask is None else take(channel_mask, rows)
+            # a block's kernels read their tiles from HBM, as the full
+            # round's do, and not from a VMEM copy the compiler would make
+            # of operands this small (kernels.common.in_hbm)
+            g_ghat, g_err, censor, st = self._shard_rows(
+                sub, grad_rows(rows), ssq, ids,
+                (rows < m).astype(jnp.float32), gate, hbm=True)
+            ghat = jax.tree_util.tree_map(
+                lambda x, v: put(x, rows, v), ghat, g_ghat)
+            err = jax.tree_util.tree_map(
+                lambda x, v, r: put(x, rows, v) if r else v,
+                err, g_err, row_leaves)
+            return (j + 1, ghat, err, put(att, rows, st.attempted),
+                    put(dlv, rows, st.delivered), put(msk, rows, st.mask),
+                    put(dsq, rows, st.delta_sq), censor)
+
+        zeros = jnp.zeros((m,), jnp.float32)
+        _, ghat, err, att, dlv, msk, dsq, censor = jax.lax.while_loop(
+            lambda carry: carry[0] < blocks, trip,
+            (jnp.zeros((), blocks.dtype), state.ghat, state.err, zeros,
+             zeros, zeros, zeros, state.censor))
+        stats = ShardStepStats(mask=msk, attempted=att, delivered=dlv,
+                               delta_sq=dsq, step_sq=ssq)
+        return self._shard_finish(state, params, ghat, err, censor, stats)
 
     def _decide(self, censor_state, dsq, ssq, worker_ids):
         if worker_ids is None:

@@ -173,6 +173,29 @@ def test_run_mesh_setup_counts_tiled_leaves(route, want):
     assert setup.stats["tiled_leaves"] == want
 
 
+@pytest.mark.parametrize("draws,want", [
+    ({}, 0), ({"participation": 0.7, "loss_prob": 0.3}, 0),
+    ({"participation": 0.05, "loss_prob": 0.2, "quorum": 0.8}, 32)],
+    ids=["sync", "full", "gathered"])
+def test_run_mesh_setup_counts_cohort_rows(draws, want):
+    """The set-up span gives the rows of each shard round's gathered
+    cohort, 0 where the round steps every writer; each round's account
+    span then says how many blocks of them the round took (one, as the
+    capacity holds these cohorts), and the sync reads what it always
+    does."""
+    from test_cohort_gather import make_opt, mlr_task
+    o = make_opt("dense-reference")
+    _, spans = _traced(lambda: run_mesh(
+        o, mlr_task(), ROUNDS, scenario=MeshScenario(seed=3, **draws)))
+    setup, = _named(spans, "run_mesh/setup")
+    assert setup.stats["cohort_rows"] == want
+    chunks = [s.stats.get("cohort_chunks")
+              for s in _named(spans, "run_mesh/account")]
+    assert chunks == [1 if want else None] * ROUNDS
+    assert [s.stats["reads"] for s in _named(spans, "run_mesh/sync")] == \
+        [7 + 1 + 1] * ROUNDS
+
+
 def test_run_mesh_same_with_profiler_on_and_off(mesh_run, mesh_traced):
     traced, _ = mesh_traced
     plain = mesh_run()

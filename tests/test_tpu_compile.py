@@ -39,7 +39,8 @@ from jax.sharding import (AxisType, Mesh, NamedSharding, PartitionSpec,
 
 from repro import opt
 from repro.core.simulator import FedTask
-from repro.fed.mesh import MeshScenario, make_server_round, make_shard_round
+from repro.fed.mesh import (MeshScenario, cohort_capacity, make_server_round,
+                            make_shard_round)
 from repro.kernels import (censor, common, fused_step, hb_update, lowrank_ef,
                            quantize_ef, topk_pack)
 
@@ -190,6 +191,60 @@ def test_fed_mesh_server_round_compiles_on_four_chips(topo, monkeypatch):
                                                 theta).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "all-reduce" in text
+
+
+def test_fed_mesh_cohort_round_steps_only_its_rows(one_chip, monkeypatch):
+    """The one-chip shard round of the benchmark's cohort traffic (about
+    100 of 3400 writers drawn a round) at emnist62-mlr's shapes, donated:
+    the gradient and both bank kernels run on the gathered cohort's C
+    rows, and neither gathering the rows nor putting them back moves a
+    bank- or image-sized array."""
+    monkeypatch.setattr(common, "interpret_default", lambda: False)
+    m, pixels, classes, samples = 3400, 784, 62, 512
+    scenario = MeshScenario(participation=1 / 34, loss_prob=0.1,
+                            quorum=0.8, seed=2**31 + 1)
+    o = opt.make("chb", 0.05, m, beta=0.4, eps1_scale=0.5, backend="pallas")
+    c = cohort_capacity(m, scenario, o.censor)
+    assert c == 160
+    params = {"W": _shape(one_chip, (pixels, classes)),
+              "b": _shape(one_chip, (classes,))}
+    state = jax.tree_util.tree_map(
+        lambda x: _shape(one_chip, x.shape, x.dtype),
+        jax.eval_shape(o.shard_init, params))
+    data = {"x": _shape(one_chip, (m, samples, pixels)),
+            "y": _shape(one_chip, (m, samples), jnp.int32),
+            "n": _shape(one_chip, (m,), jnp.int32)}
+    fn = make_shard_round(o, _mlr_task(params, 671_585), scenario)
+    text = jax.jit(fn, donate_argnums=(0,)).lower(
+        state, params, data, _shape(one_chip, (m,), jnp.uint32),
+        _shape(one_chip, (m,)), _shape(one_chip, (m,)),
+        _shape(one_chip, (), jnp.int32)).compile().as_text()
+
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line and " custom-call(" in line]
+    names = {re.search(r"kernels/(\w+)/pallas_call", line).group(1)
+             for line in kernels}
+    assert names == {"tree_delta_sqnorms", "tree_censor_bank_advance"}
+    for line in kernels:
+        result = line.split(" custom-call(")[0]
+        operands = re.search(r"operand_layout_constraints=\{(.*?)\}, ",
+                             line).group(1)
+        leading = {int(dims.split(",")[0])
+                   for dims in _SHAPE.findall(result + operands)
+                   if "," in dims}
+        assert leading == {c}, line[:300]
+        # tiles held in HBM, as at full size, so the kernels' own time
+        # holds their reads (kernels.common.in_hbm)
+        assert '"input_memory_space_colors"' in line, line[:300]
+    # the client gradient xᵀ·r of every writer is gone; only the loss
+    # that makes the objective still reads every writer
+    assert f"f32[{m},{pixels},{classes}]" not in text
+    # nothing bank- or image-sized is re-laid out, cut into pieces or
+    # rounded: the bank is put back in place, and the images are read
+    # one row at a time
+    moved = [op for op in _bank_sized(text, m, pixels * classes)
+             if op in ("pad", "copy", "slice", "convert")]
+    assert moved == [], moved
 
 
 def _mlr_task(params, images: int):
