@@ -35,19 +35,12 @@ import dataclasses
 import warnings
 from typing import Any, Optional
 
-import jax
-
 
 def _static_pos(x) -> Optional[bool]:
-    """``bool(x > 0)`` for static scalars; ``None`` when ``x`` is traced.
-
-    Duplicated from ``repro.opt.api.static_pos`` (3 lines) so this module
-    needs no import-time dependency on ``repro.opt`` — core and opt import
-    each other's *submodules* lazily to stay cycle-free.
-    """
-    if isinstance(x, jax.core.Tracer):
-        return None
-    return bool(x > 0)
+    """``repro.opt.api.static_pos``, imported at call time: core and opt
+    import each other's *submodules* lazily to stay cycle-free."""
+    from ..opt.api import static_pos
+    return static_pos(x)
 
 
 def __getattr__(name):

@@ -99,7 +99,7 @@ def trajectory(cfg: OptLike, task: FedTask, num_iters: int,
         is part of the compiled program's identity). The bag rides
         *alongside* the optimizer state: every state-carried value is
         bit-identical to a metrics-off run (tests/test_obs.py pins this
-        against the golden fingerprints).
+        against the pinned runs).
     Returns:
       The full ``History`` of the run (see its docstring).
 

@@ -185,6 +185,11 @@ def make_neural_network(m: int = 9, n_per: int = 200, d: int = 22,
 
     Binary labels; sigmoid output with squared loss + L2 regularization.
     Progress metric is ||grad_k||^2 (StepInfo.agg_grad_sqnorm).
+
+    The data comes from numpy's generator; the initial weights come from
+    ``jax.random.normal``, so they depend on JAX's threefry mode
+    (``jax_threefry_partitionable``): the same seed draws another init in
+    each mode.
     """
     rng = np.random.default_rng(seed)
     n_total = m * n_per

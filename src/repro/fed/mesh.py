@@ -26,9 +26,10 @@ multi-device legs in tests/test_distributed.py; contracts stated in
 docs/fed_scaling.md):
 
   (a) **sync anchor** — the ideal scenario (participation 1, loss 0,
-      quorum 1) sharded over ONE device is bit-identical to
-      ``core.simulator.run``: objective, masks, ``agg_grad_sqnorm``,
-      final params, uplink counts.
+      quorum 1) sharded over ONE device computes ``core.simulator.run``'s
+      run: masks, uplink counts, objective and final params bit-identical;
+      the diagnostic ``agg_grad_sqnorm``, a sum of squares the two
+      programs fuse differently, within a few ulps.
   (b) **K-invariance** — the same run over K shards draws the *same*
       participation/loss/censor decisions for every client (masks
       bit-equal for K in {1, 2, 8}); float trajectories agree to the
@@ -348,13 +349,14 @@ def run_mesh(cfg, task: FedTask, num_rounds: int, *,
         donation lets XLA reuse them across rounds.
       bake_data: fold each shard's data block into its round program as a
         compile-time constant (one trace per shard) instead of passing it
-        as a jit argument (one shared trace). The default matches what
+        as a jit argument (one shared trace). Baked data is what
         ``simulator.run``'s scan does with its closed-over
-        ``worker_data`` — and that is load-bearing for anchor (a): on
-        dot-product tasks XLA contracts a *constant* operand differently
-        from a parameter operand by ~1 ulp, so argument-passed data is
-        only ``allclose`` to the scan, not bit-identical. Pass ``False``
-        at 10^5+ clients, where constant-folding the data bloats the
+        ``worker_data``, and only then is anchor (a)'s objective bitwise:
+        on dot-product tasks XLA contracts a *constant* operand
+        differently from a parameter operand by ~1 ulp, so with
+        argument-passed data the floats are ``allclose`` to the scan
+        while draws, masks and counts stay identical. Pass ``False`` at
+        10^5+ clients, where constant-folding the data bloats the
         executable and compile time; element-wise tasks
         (``data.edge_tasks.make_edge_quadratics``) lose nothing either
         way, and the K-invariance anchor (b) holds in both modes.

@@ -80,8 +80,10 @@ def force_staged():
     """Trace the staged per-stage kernels instead of the fused megakernel.
 
     For A/B comparison only (conformance tests, the roofline benchmark's
-    staged-vs-fused columns): both programs are bit-identical at f32/f64,
-    the staged one just moves more bytes.
+    staged-vs-fused columns): both programs compute one run at f32/f64 —
+    masks, counts, params and bank bit-identical, the diagnostic
+    ``agg_grad_sqnorm`` within a few ulps (tests/exactness.py) — and the
+    staged one just moves more bytes.
     """
     global _FUSION_ENABLED
     prev = _FUSION_ENABLED

@@ -4,23 +4,23 @@ The ``repro.opt`` registries are open: registering a new censor, transport,
 or server kind instantly makes it reachable from every builder, sweep, and
 JSON spec. The test suite pins behavior per *kind* — the transport
 conformance suite parametrizes over ``TRANSPORT_KINDS`` at collection time
-and the golden-fingerprint tables key hex fingerprints by kind string — so
-a kind that exists in the registry but never appears in those files ships
-unpinned: nothing fails when its numerics drift.
+and the pinned-run tests name each kind by string — so a kind that exists
+in the registry but never appears in those files ships unpinned: nothing
+fails when its numerics drift.
 
 This project rule parses ``src/repro/opt/registry.py`` for the three
 ``*_KINDS`` dict literals and requires every key to appear as a string
 literal in its pin files:
 
   * transport kinds -> ``tests/transport_conformance.py`` (the contract
-    suite's kind vocabulary) AND ``tests/test_backend.py`` (the golden
-    fingerprint tables);
+    suite's kind vocabulary) AND ``tests/test_backend.py`` (the pinned
+    runs of every transport);
   * censor + server kinds -> ``tests/test_opt.py`` (spec round-trip and
-    golden tables).
+    pinned runs).
 
 It is a tripwire, not a coverage proof: the literal's presence is checked
 textually (AST string constants), which is exactly the level at which the
-"I registered a kind and forgot the goldens" mistake happens.  Outside a
+"I registered a kind and forgot the pins" mistake happens.  Outside a
 repo with that layout the rule is silent.
 """
 from __future__ import annotations
@@ -62,8 +62,8 @@ def _kind_tables(tree: ast.Module) -> dict[str, tuple[int, set[str]]]:
 @project_rule("registry-kind-unpinned",
               "every kind in the censor/transport/server registries must "
               "appear in the conformance-suite parametrization and the "
-              "golden-fingerprint tables — an unpinned kind ships with no "
-              "drift tripwire")
+              "pinned-run tests — an unpinned kind ships with no drift "
+              "tripwire")
 def check(ctx):
     registry_tree = ctx.read_project_file(_REGISTRY)
     if registry_tree is None:

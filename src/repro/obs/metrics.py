@@ -11,7 +11,7 @@ execution surface.
 Collection is strictly **read-only**: every entry is computed *from* the
 optimizer state and step stats the run already produced, never fed back
 into them, so a metrics-on trajectory is bit-identical to a metrics-off
-one (pinned by tests/test_obs.py against the golden fingerprints) and the
+one (pinned by tests/test_obs.py against the pinned runs) and the
 bag can be dropped without touching the compiled step's math.
 
 Two layers of observables:

@@ -25,6 +25,7 @@ from typing import TYPE_CHECKING, Any, NamedTuple, Optional, Protocol, \
     runtime_checkable
 
 import jax
+import numpy as np
 
 if TYPE_CHECKING:   # annotation only: keeps this module import-cycle-free
     from ..core.accounting import CommStats
@@ -112,12 +113,20 @@ class FedOptimizer(Protocol):
 
 
 def static_pos(x) -> Optional[bool]:
-    """``bool(x > 0)`` for static scalars; ``None`` when ``x`` is traced.
+    """``x > 0`` as the device decides it, for static scalars; ``None``
+    when ``x`` is traced.
 
     The stages use this to keep *structural* decisions (does a state buffer
     exist? which censor branch compiles?) out of traced code while still
-    letting hyperparameter *values* be traced by the sweep engine.
+    letting hyperparameter *values* be traced by the sweep engine. The
+    value is taken in the dtype JAX would give it, and a subnormal counts
+    as zero: XLA flushes subnormals to zero, so that is what a traced
+    ``x > 0`` decides, and a static threshold must pick the same branch.
     """
     if isinstance(x, jax.core.Tracer):
         return None
-    return bool(x > 0)
+    v = np.asarray(x)
+    v = v.astype(jax.dtypes.canonicalize_dtype(v.dtype))
+    if np.issubdtype(v.dtype, np.floating):
+        return bool(v >= np.finfo(v.dtype).tiny)
+    return bool(v > 0)

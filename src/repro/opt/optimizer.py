@@ -4,7 +4,7 @@ This is the former ``core/chb.step`` body, refactored so that the three
 orthogonal decisions (censor / transport / server) are stage calls instead
 of hard-wired branches. Every composition expressible by the old
 ``FedOptConfig`` produces a bit-identical program (pinned by
-``tests/test_opt.py``'s golden fingerprints and the ``tests/test_sweep.py``
+``tests/test_opt.py``'s pinned runs and the ``tests/test_sweep.py``
 exactness grids); new algorithms are new compositions.
 """
 from __future__ import annotations
@@ -345,13 +345,15 @@ class ComposedOptimizer:
         the reference path exactly; what may differ is XLA's fusion of
         the jnp side (FMA contraction on large tensors) and the tiled
         partial-sum order of the sqnorm reductions — both ulp-level per
-        step. Golden-pinned bit-identical on the paper-scale tasks
-        (tests/test_backend.py); on much larger tensors trajectories can
-        drift by compounded ulps while censor masks and uplink counts
-        stay aligned (see docs/kernels.md). Sub-f32 params compute in
-        f32 in-kernel and therefore genuinely diverge from the
-        reference's native-bf16 arithmetic (they match the ``ref.py``
-        oracles instead).
+        step. On the paper-scale tasks masks, counts, objective, params
+        and bank are bit-identical to the reference; the diagnostic
+        ``agg_grad_sqnorm``, a sum of squares each program fuses its own
+        way, agrees to a few ulps (tests/exactness.py). On much larger
+        tensors trajectories can drift by compounded ulps while censor
+        masks and uplink counts stay aligned (see docs/kernels.md).
+        Sub-f32 params compute in f32 in-kernel and therefore genuinely
+        diverge from the reference's native-bf16 arithmetic (they match
+        the ``ref.py`` oracles instead).
         """
         # fused megakernel routing (kernels/fused_step.py): dense and
         # int8+EF run the whole post-``decide`` tail as ONE sweep per
@@ -405,14 +407,6 @@ class ComposedOptimizer:
             agg = tree_sum_leading(new_ghat)
             new_params = self.apply_server(params, state.prev_params, agg)
         per_tx_bytes = self.transport.payload_bytes(params)
-
-        if dense_fused or int8_fused:
-            # diagnostic-only recompute: the kernel's agg output is
-            # bitwise-identical, but a sqnorm fused over a sliced pallas
-            # buffer groups its reduction differently from one fused over
-            # the host sum — recomputing keeps the stat's HLO subgraph
-            # identical to the staged/reference path (tier-1 bit parity)
-            agg = tree_sum_leading(new_ghat)
         stats = StepStats(mask=mask, delta_sq=dsq, step_sq=ssq,
                           agg_grad_sqnorm=tree_sqnorm(agg))
         new_state = OptState(

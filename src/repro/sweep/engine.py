@@ -15,7 +15,10 @@ what made dense hyperparameter frontiers dispatch-bound — the engine:
 
 Exactness contract: the default ``lax.map`` execution traces the per-point
 program with exactly the shapes ``simulator.run`` uses, so trajectories are
-**bit-identical** to per-point runs (asserted by tests/test_sweep.py).
+**bit-identical** to per-point runs of the same backend, diagnostics
+included (asserted by tests/test_sweep.py through tests/exactness.py).
+Across backends a sweep holds the two-program contract: decisions and
+trajectory bitwise, the f32 ``agg_grad_sqnorm`` within a few ulps.
 ``vectorize=True`` batches the gradient matmuls across points, which is
 faster for large grids of tiny problems but perturbs float reductions by
 ~1 ulp per iteration — enough to flip an occasional f32 censor decision
@@ -23,12 +26,12 @@ near the numerical floor. Use it when throughput matters more than
 bit-reproducibility.
 
 Seeds: multiple ``seed`` values require a ``task_factory(seed, num_workers)
--> FedTask``. Task data is closed over as program constants — exactly as
-``simulator.run`` does, which is what keeps the trajectories bit-identical
-(passing the data as a program argument, or gathering it from a stacked
-bank, perturbs XLA's matmul lowering by ~1 ulp) — so each distinct seed is
-its own compiled partition. A 16-point eps-grid over 2 seeds compiles twice
-instead of 32 times.
+-> FedTask``. Task data is closed over as program constants, as
+``simulator.run`` does; passing the data as a program argument, or
+gathering it from a stacked bank, perturbs XLA's matmul lowering by ~1 ulp,
+so the rows would no longer be bitwise those of per-point runs. Each
+distinct seed is therefore its own compiled partition: a 16-point eps-grid
+over 2 seeds compiles twice instead of 32 times.
 """
 from __future__ import annotations
 

@@ -4,9 +4,9 @@ Pins the tentpole contract of the backend redesign:
 
   * ``opt.make(name, backend="pallas")`` runs end-to-end through
     ``simulator.run``, ``sweep.run_sweep`` and the ``repro.fed`` event
-    runtime, **bit-identical** to the reference backend at f32 and f64
-    (in interpret mode on this container) — pinned both by direct
-    history comparison and by golden hex fingerprints;
+    runtime, computing the same run as the reference backend at f32 and
+    f64 (in interpret mode on the CPU) — held by ``exactness``'s
+    two-program contract and by the recorded pins;
   * specs round-trip the backend through JSON;
   * sweeping (alpha, beta, eps1) over a pallas composition compiles ONE
     program and traces each kernel dispatch exactly once (the
@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import exactness
 from repro import fed, opt, sweep
 from repro.core import simulator
 from repro.data import paper_tasks
@@ -56,50 +57,11 @@ def task32(linreg):
     return _as_f32(linreg.task)
 
 
-def _fingerprint(h):
-    obj = np.asarray(h.objective)
-    fsq = float(sum(np.sum(np.square(np.asarray(x, np.float64)))
-                    for x in jax.tree_util.tree_leaves(h.final_params)))
-    return (float(obj[-1]).hex(), float(obj.sum()).hex(),
-            int(np.asarray(h.comm_cum)[-1]),
-            int(np.asarray(h.mask).sum()),
-            float(np.asarray(h.agg_grad_sqnorm)[-1]).hex(), fsq.hex())
-
-
-def _assert_histories_equal(h1, h2):
-    for f in ("objective", "mask", "comm_cum", "agg_grad_sqnorm"):
-        np.testing.assert_array_equal(np.asarray(getattr(h1, f)),
-                                      np.asarray(getattr(h2, f)), err_msg=f)
-    for a, b in zip(jax.tree_util.tree_leaves(h1.final_params),
-                    jax.tree_util.tree_leaves(h2.final_params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    for a, b in zip(jax.tree_util.tree_leaves(h1.final_state.ghat),
-                    jax.tree_util.tree_leaves(h2.final_state.ghat)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-# Golden hex fingerprints of the f32 chb run (60 iters, m=5/n=30/d=20
-# linreg task at alpha_paper), recorded from the REFERENCE backend — the
-# pallas backend must reproduce them bit-for-bit.
-GOLDEN_CHB_F32 = ("0x1.107a260000000p+6", "0x1.0024fc0000000p+12",
-                  262, 262, "0x1.dc40000000000p-42",
-                  "0x1.a94328858133cp+1")
-
-# Per-transport golden pins for the same run, at conformance-scale
-# hyperparameters (k=8 actually sparsifies the d=20 task; lowrank ships
-# vector leaves dense, so its trajectory — deliberately — equals the
-# dense pin). A transport registered without an entry here FAILS
+# Transport hyperparameters of the ``f32/<kind>`` pins: k=8 actually
+# sparsifies the d=20 task. A transport registered without a pin FAILS
 # ``test_golden_fingerprints_all_transports`` loudly instead of going
 # uncovered.
 GOLDEN_TRANSPORT_KW = {"topk": {"k": 8}, "lowrank": {"rank": 2}}
-GOLDEN_TRANSPORT_F32 = {
-    "dense": GOLDEN_CHB_F32,
-    "int8": ("0x1.107a260000000p+6", "0x1.00251e0000000p+12", 259, 259,
-             "0x1.7e80000000000p-41", "0x1.a94328064f2b5p+1"),
-    "topk": ("0x1.107a280000000p+6", "0x1.0075ec0000000p+12", 295, 295,
-             "0x1.baecd80000000p-13", "0x1.a943cf7d37977p+1"),
-    "lowrank": GOLDEN_CHB_F32,
-}
 
 
 # ------------------------------------------------------- simulator parity
@@ -112,63 +74,79 @@ GOLDEN_TRANSPORT_F32 = {
 def test_simulator_bitwise_f32(linreg, task32, name, kw):
     o_ref = opt.make(name, linreg.alpha_paper, M, **kw)
     o_pal = opt.make(name, linreg.alpha_paper, M, backend="pallas", **kw)
-    _assert_histories_equal(simulator.run(o_ref, task32, ITERS),
-                            simulator.run(o_pal, task32, ITERS))
+    exactness.assert_same_run(simulator.run(o_ref, task32, ITERS),
+                              simulator.run(o_pal, task32, ITERS))
 
 
 @pytest.mark.parametrize("kw", [{}, {"quantize": "int8"}])
 def test_simulator_bitwise_f64(linreg, kw):
     o_ref = opt.make("chb", linreg.alpha_paper, M, **kw)
     o_pal = opt.make("chb", linreg.alpha_paper, M, backend="pallas", **kw)
-    _assert_histories_equal(simulator.run(o_ref, linreg.task, ITERS),
-                            simulator.run(o_pal, linreg.task, ITERS))
+    exactness.assert_same_run(simulator.run(o_ref, linreg.task, ITERS),
+                              simulator.run(o_pal, linreg.task, ITERS))
 
 
 def test_golden_fingerprints_both_backends(linreg, task32):
-    """Both backends reproduce the recorded golden hex trajectory.
+    """Both backends reproduce the recorded run.
 
     The pallas leg runs the one-sweep fused step (its default route), so
-    this golden also pins the megakernel against the reference bits."""
+    this pin also holds the megakernel to the reference's run."""
     for backend in opt.BACKENDS:
         o = opt.make("chb", linreg.alpha_paper, M, backend=backend)
-        got = _fingerprint(simulator.run(o, task32, ITERS))
-        assert got == GOLDEN_CHB_F32, (backend, got)
+        exactness.assert_matches_pin(
+            simulator.run(o, task32, ITERS, collect_metrics=True),
+            exactness.PINS["f32/dense"])
 
 
 @pytest.mark.parametrize("kind", ["dense", "int8"])
 def test_golden_fingerprints_staged_pallas(linreg, task32, kind):
-    """``force_staged()`` pins the pre-fusion kernel chain to the SAME
-    goldens: the fused and staged pallas routes may never drift apart."""
+    """``force_staged()`` holds the pre-fusion kernel chain to the SAME
+    pins: the fused and staged pallas routes may never drift apart."""
     from repro.kernels import fused_step
     t = opt.make_transport(kind)
     o = opt.make("chb", linreg.alpha_paper, M, transport=t,
                  backend="pallas")
     with fused_step.force_staged():
-        got = _fingerprint(simulator.run(o, task32, ITERS))
-    assert got == GOLDEN_TRANSPORT_F32[kind], (kind, got)
+        h = simulator.run(o, task32, ITERS, collect_metrics=True)
+    exactness.assert_matches_pin(h, exactness.PINS[f"f32/{kind}"])
 
 
 @pytest.mark.parametrize("kind", sorted(opt.TRANSPORT_KINDS))
 def test_golden_fingerprints_all_transports(linreg, task32, kind):
-    """Every registered transport has a golden pin, reproduced bit-for-bit
-    by BOTH backends. A new registry entry without a pin fails the first
-    assert — record one instead of shipping an uncovered transport."""
-    assert kind in GOLDEN_TRANSPORT_F32, (
-        f"transport {kind!r} is registered but has no golden fingerprint; "
-        "add a GOLDEN_TRANSPORT_F32 entry (and GOLDEN_TRANSPORT_KW "
+    """Every registered transport has a pin, reproduced by BOTH backends.
+    A new registry entry without a pin fails the first assert — record
+    one instead of shipping an uncovered transport."""
+    assert f"f32/{kind}" in exactness.PINS, (
+        f"transport {kind!r} is registered but has no pin; add an "
+        f"exactness.PINS['f32/{kind}'] entry (and GOLDEN_TRANSPORT_KW "
         "hyperparameters if the defaults are a no-op on the d=20 task)")
     t = opt.make_transport(kind, **GOLDEN_TRANSPORT_KW.get(kind, {}))
     for backend in opt.BACKENDS:
         o = opt.make("chb", linreg.alpha_paper, M, transport=t,
                      backend=backend)
-        got = _fingerprint(simulator.run(o, task32, ITERS))
-        assert got == GOLDEN_TRANSPORT_F32[kind], (kind, backend, got)
+        exactness.assert_matches_pin(
+            simulator.run(o, task32, ITERS, collect_metrics=True),
+            exactness.PINS[f"f32/{kind}"])
+
+
+@pytest.mark.parametrize("kind,pin", [("dense", "int8"), ("int8", "dense"),
+                                      ("topk", "dense")])
+def test_golden_fingerprint_rejects_another_transport(linreg, task32, kind,
+                                                      pin):
+    """A transport's run fails another transport's pin: dense and int8
+    take the same decisions before the floor and part on the uplink
+    bytes; top-k parts on its uplinks."""
+    t = opt.make_transport(kind, **GOLDEN_TRANSPORT_KW.get(kind, {}))
+    o = opt.make("chb", linreg.alpha_paper, M, transport=t)
+    h = simulator.run(o, task32, ITERS, collect_metrics=True)
+    with pytest.raises(AssertionError):
+        exactness.assert_matches_pin(h, exactness.PINS[f"f32/{pin}"])
 
 
 def test_pytree_task_bitwise(linreg):
     bn = paper_tasks.make_neural_network(m=4, n_per=40, d=8, hidden=6)
     t32 = _as_f32(bn.task)
-    _assert_histories_equal(
+    exactness.assert_same_run(
         simulator.run(opt.make("chb", 0.02, 4), t32, 25),
         simulator.run(opt.make("chb", 0.02, 4, backend="pallas"), t32, 25))
 
@@ -229,10 +207,7 @@ def test_sweep_pallas_bitwise_one_program(linreg, task32):
                                        "tree_fused_dense_step": 1}
     res_r = sweep.run_sweep(grid, task32, num_iters=40, base_cfg=base_r)
     for i in range(len(res_p)):
-        hp, hr = res_p.history(i), res_r.history(i)
-        for f in ("objective", "mask", "comm_cum", "agg_grad_sqnorm"):
-            np.testing.assert_array_equal(np.asarray(getattr(hp, f)),
-                                          np.asarray(getattr(hr, f)))
+        exactness.assert_same_run(res_p.history(i), res_r.history(i))
         assert res_p.specs[i]["backend"] == "pallas"
         assert res_r.specs[i]["backend"] == "reference"
     # sweep rows == per-point pallas simulator.run (the PR-2 exactness
@@ -242,9 +217,8 @@ def test_sweep_pallas_bitwise_one_program(linreg, task32):
                             base_cfg=base_p)
     pt = res64.points[3]
     o = base_p.with_hparams(alpha=pt.alpha, beta=pt.beta, eps1=pt.eps1)
-    h = simulator.run(o, linreg.task, 40)
-    np.testing.assert_array_equal(np.asarray(h.objective),
-                                  np.asarray(res64.history(3).objective))
+    exactness.assert_bitwise(simulator.run(o, linreg.task, 40),
+                             res64.history(3))
 
 
 # ----------------------------------------------------------- fed runtime
@@ -257,26 +231,18 @@ def test_fed_pallas_bitwise(linreg):
         h_pal = fed.run_edge(
             opt.make("chb", linreg.alpha_paper, M, backend="pallas", **kw),
             linreg.task, edge, 30)
-        for f in ("objective", "mask", "comm_cum", "agg_grad_sqnorm"):
-            np.testing.assert_array_equal(getattr(h_ref, f),
-                                          getattr(h_pal, f), err_msg=f)
-        for a, b in zip(jax.tree_util.tree_leaves(h_ref.final_params),
-                        jax.tree_util.tree_leaves(h_pal.final_params)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        exactness.assert_bitwise(h_ref, h_pal)
 
 
 def test_fed_pallas_sync_anchor(linreg):
     """Pallas fed == pallas simulator on the sync anchor: draws, masks
-    and uplinks exact; objectives to the anchor tolerance (gradient
-    evaluation is per-row there vs vmapped in the simulator)."""
+    and uplinks exact; objectives within ``exactness.BOUNDS``'s
+    ``event_runtime`` (per-row gradients there, vmapped here)."""
     o = opt.make("csgd", linreg.alpha_paper, M, tau0=5.0,
                  backend="pallas")
     hs = simulator.run(o, linreg.task, 25)
     he = fed.run_edge(o, linreg.task, fed.sync_config(M), 25)
-    np.testing.assert_array_equal(np.asarray(hs.mask), he.mask)
-    np.testing.assert_array_equal(np.asarray(hs.comm_cum), he.comm_cum)
-    np.testing.assert_allclose(np.asarray(hs.objective), he.objective,
-                               rtol=1e-9)
+    exactness.assert_close_run(he, hs, "event_runtime")
 
 
 # -------------------------------------------------- multi-tile numerics
@@ -300,13 +266,7 @@ def test_multitile_masks_aligned_trajectories_close():
     h_ref = simulator.run(opt.make("chb", 0.05, m, eps1=0.3), task, 25)
     h_pal = simulator.run(opt.make("chb", 0.05, m, eps1=0.3,
                                    backend="pallas"), task, 25)
-    np.testing.assert_array_equal(np.asarray(h_ref.mask),
-                                  np.asarray(h_pal.mask))
-    np.testing.assert_array_equal(np.asarray(h_ref.comm_cum),
-                                  np.asarray(h_pal.comm_cum))
-    np.testing.assert_allclose(np.asarray(h_ref.objective),
-                               np.asarray(h_pal.objective),
-                               rtol=1e-3, atol=1e-9)
+    exactness.assert_close_run(h_ref, h_pal, "multitile")
 
 
 # ----------------------------------------------------- distributed hook

@@ -8,7 +8,6 @@ the eq.-(5) partial sum. These tests hold that route to the untiled
 ``shard_step`` it replaces, and each tiled kernel core to the padding
 entry point that every other caller keeps.
 """
-import json
 import os
 import subprocess
 import sys
@@ -19,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import exactness
 from repro import opt
 from repro.core.simulator import FedTask
 from repro.fed.mesh import MeshScenario, run_mesh
@@ -65,9 +65,10 @@ def pallas_chb(m: int = M):
                     backend="pallas")
 
 
-def compare_routes(shards: int) -> dict:
+def compare_routes(shards: int):
     """The tiled and the untiled route over ``shards`` shards, on the
-    same draws: what must be equal, and how far theta moved apart."""
+    same draws, compute one run bit for bit (on the CPU the axis-0
+    worker-sum over tiles adds in the order it does untiled)."""
     from repro.launch.mesh import make_client_mesh
 
     task, o = mlr_task(), pallas_chb()
@@ -88,32 +89,13 @@ def compare_routes(shards: int) -> dict:
     finally:
         ComposedOptimizer.bank_tiles = prop
 
-    def flat(h):
-        return np.concatenate([np.ravel(np.asarray(x)) for x in
-                               jax.tree_util.tree_leaves(h.final_params)])
-    out = {f: bool(np.array_equal(getattr(tiled, f), getattr(plain, f)))
-           for f in ("mask", "participated", "attempted", "delivered",
-                     "quorum_met", "comm_cum", "delivered_cum",
-                     "bytes_cum", "objective", "agg_grad_sqnorm")}
-    out["params"] = bool(np.array_equal(flat(tiled), flat(plain)))
-    out["objective_gap"] = float(np.max(np.abs(tiled.objective
-                                               - plain.objective)))
     # the draws must leave both decisions in play, or masks prove little
-    out["mixed_mask"] = bool(0 < tiled.mask.sum() < tiled.mask.size)
-    return out
-
-
-def _assert_same(out: dict):
-    assert out["mixed_mask"], out
-    # masks, counts and bytes exact; theta bit for bit (on the CPU the
-    # axis-0 worker-sum over tiles adds in the order it does untiled)
-    assert all(v for k, v in out.items()
-               if k not in ("objective_gap", "mixed_mask")), out
-    assert out["objective_gap"] == 0.0, out
+    assert 0 < tiled.mask.sum() < tiled.mask.size
+    exactness.assert_bitwise(tiled, plain)
 
 
 def test_tiled_bank_matches_untiled_one_shard():
-    _assert_same(compare_routes(1))
+    compare_routes(1)
 
 
 def test_tiled_bank_matches_untiled_two_shards():
@@ -122,14 +104,13 @@ def test_tiled_bank_matches_untiled_two_shards():
         import json, sys
         sys.path.insert(0, {TESTS!r})
         from test_bank_tiles import compare_routes
-        print(json.dumps(compare_routes(2)))
+        compare_routes(2)
     """)
     env = {**os.environ, "PYTHONPATH": os.path.join(TESTS, "..", "src"),
            "XLA_FLAGS": "--xla_force_host_platform_device_count=2"}
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]
-    _assert_same(json.loads(r.stdout.splitlines()[-1]))
 
 
 def test_bank_tiles_only_on_the_pallas_dense_route():

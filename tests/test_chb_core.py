@@ -9,6 +9,7 @@ import pytest
 pytest.importorskip("hypothesis")  # property tests need the dev deps
 from hypothesis import given, settings, strategies as st
 
+import exactness
 from repro.core import baselines, chb, simulator
 from repro.core.censoring import check_feasible, paper_eps1, theoretical_params
 from repro.data import paper_tasks
@@ -53,8 +54,7 @@ def test_matches_literal_algorithm1(linreg):
     cfg = baselines.chb(linreg.alpha_paper, 5)
     hist = simulator.run(cfg, linreg.task, 50)
     ref_obj, ref_comms = reference_algorithm1(cfg, linreg.task, 50)
-    np.testing.assert_allclose(np.asarray(hist.objective), ref_obj,
-                               rtol=1e-8, atol=1e-8)
+    exactness.assert_close(hist.objective, ref_obj, "literal_algorithm1")
     np.testing.assert_array_equal(np.asarray(hist.comm_cum, int), ref_comms)
 
 
@@ -260,8 +260,8 @@ def test_per_tensor_censoring():
     c2 = dataclasses.replace(c1, granularity="per_tensor")
     h1 = simulator.run(c1, b.task, 150)
     h2 = simulator.run(c2, b.task, 150)
-    np.testing.assert_allclose(np.asarray(h1.objective),
-                               np.asarray(h2.objective), rtol=1e-10)
+    exactness.assert_close(h1.objective, h2.objective,
+                           "per_tensor_one_leaf")
 
     bn = paper_tasks.make_neural_network(m=5, n_per=100, d=10)
     cg = baselines.chb(0.02, 5)

@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import exactness
 from repro import opt
 from repro.core.simulator import FedTask
 from repro.fed import mesh as fed_mesh
@@ -34,7 +35,7 @@ EXACT = ("mask", "participated", "attempted", "delivered", "quorum_met",
          "comm_cum", "delivered_cum", "bytes_cum")
 # objective and theta: the cohort's gradients are the same row math in a
 # batch of C rows; allowed to differ by float rounding, not by a decision
-RTOL, ATOL = 1e-5, 1e-7
+RTOL, ATOL = exactness.BOUNDS["cohort_gather"]
 ROUTES = {"dense-reference": {}, "dense-pallas": {"backend": "pallas"},
           "int8-reference": {"quantize": "int8"},
           "int8-pallas": {"quantize": "int8", "backend": "pallas"}}

@@ -8,6 +8,8 @@ import textwrap
 
 import pytest
 
+import exactness
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
@@ -80,9 +82,8 @@ def test_scan_strategy_matches_single_device_reference():
                           "ref_tx": ref_tx, "tx": txs}))
     """)
     out = run_sub(code)
-    import numpy as np
-    np.testing.assert_allclose(out["losses"], out["ref_losses"],
-                               rtol=2e-4, atol=2e-4)
+    exactness.assert_close(out["losses"], out["ref_losses"],
+                           "sharded_trainer")
     assert out["tx"] == out["ref_tx"]
 
 

@@ -17,9 +17,9 @@ import jax
 jax.config.update("jax_enable_x64", True)
 
 import jax.numpy as jnp
-import numpy as np
 import pytest
 
+import exactness
 from repro import opt
 from repro.core import distributed, simulator
 from repro.core.chb import FedOptConfig
@@ -49,9 +49,7 @@ def test_opt_init_prev_params_never_aliases_params(backend):
     state = o.init(params)
     assert not (_ptrs(state.prev_params) & _ptrs(params))
     # and the values still agree: the guard is a copy, not a recompute
-    for a, b in zip(jax.tree_util.tree_leaves(state.prev_params),
-                    jax.tree_util.tree_leaves(params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    exactness.leaves_equal(state.prev_params, params)
 
 
 def test_distributed_init_scan_state_never_aliases_params():
@@ -74,13 +72,7 @@ def test_simulator_donation_is_bit_identical(bundle, backend, quantize):
         init_params=jax.tree_util.tree_map(jnp.copy,
                                            bundle.task.init_params))
     h_donated = simulator.run(o, donated_task, 30, donate=True)
-    for f in ("objective", "mask", "comm_cum", "agg_grad_sqnorm"):
-        np.testing.assert_array_equal(np.asarray(getattr(h_plain, f)),
-                                      np.asarray(getattr(h_donated, f)),
-                                      err_msg=f)
-    for a, b in zip(jax.tree_util.tree_leaves(h_plain.final_params),
-                    jax.tree_util.tree_leaves(h_donated.final_params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    exactness.assert_bitwise(h_plain, h_donated)
 
 
 def test_trainer_donation_flag_is_bit_identical():
@@ -112,10 +104,4 @@ def test_fed_mesh_donation_is_bit_identical(bundle, quantize):
                       seed=9)
     plain = run_mesh(o, bundle.task, 12, scenario=sc)
     donated = run_mesh(o, bundle.task, 12, scenario=sc, donate=True)
-    for f in ("objective", "mask", "quorum_met", "agg_grad_sqnorm",
-              "attempted", "delivered"):
-        np.testing.assert_array_equal(getattr(plain, f), getattr(donated, f),
-                                      err_msg=f)
-    for a, b in zip(jax.tree_util.tree_leaves(plain.final_params),
-                    jax.tree_util.tree_leaves(donated.final_params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    exactness.assert_bitwise(plain, donated)

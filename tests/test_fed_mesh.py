@@ -1,10 +1,11 @@
 """The mesh-sharded federated runtime's single-shard contracts.
 
 Anchor (a) lives here: ``fed.run_mesh`` sharded over ONE device under the
-ideal scenario is **bit-identical** to ``core.simulator.run`` — objective,
-censor masks, aggregate norms, uplink counts, final params — across
-algorithms, backends, and transports. Everything multi-device (anchor (b),
-K-shard invariance) runs in subprocesses in tests/test_distributed.py.
+ideal scenario computes the same run as ``core.simulator.run`` — censor
+masks, uplink counts, objective and final params bitwise, the aggregate
+norm to ``exactness``'s diagnostic bound — across algorithms, backends,
+and transports. Everything multi-device (anchor (b), K-shard invariance)
+runs in subprocesses in tests/test_distributed.py.
 """
 import jax
 
@@ -14,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import exactness
 from repro import fed, opt
 from repro.core import simulator
 from repro.data import edge_tasks, paper_tasks
@@ -29,26 +31,14 @@ def bundle():
     return paper_tasks.make_linear_regression(m=M, n_per=30, d=20, seed=0)
 
 
-def _leaves_equal(a, b):
-    for x, y in zip(jax.tree_util.tree_leaves(a),
-                    jax.tree_util.tree_leaves(b)):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-
-
 @pytest.mark.parametrize("backend", sorted(opt.BACKENDS))
 @pytest.mark.parametrize("algo", ["chb", "lag", "csgd"])
 def test_sync_anchor_bitwise_dense(bundle, algo, backend):
-    """Ideal scenario, K=1: run_mesh == simulator.run bit-for-bit."""
+    """Ideal scenario, K=1: run_mesh computes simulator.run's run."""
     o = opt.make(algo, bundle.alpha_paper, M, backend=backend)
     hist = simulator.run(o, bundle.task, 12)
     mh = run_mesh(o, bundle.task, 12)
-    np.testing.assert_array_equal(np.asarray(hist.objective), mh.objective)
-    np.testing.assert_array_equal(
-        np.asarray(hist.mask).astype(np.int8), mh.mask)
-    np.testing.assert_array_equal(np.asarray(hist.agg_grad_sqnorm),
-                                  mh.agg_grad_sqnorm)
-    np.testing.assert_array_equal(np.asarray(hist.comm_cum), mh.comm_cum)
-    _leaves_equal(hist.final_params, mh.final_params)
+    exactness.assert_same_run(hist, mh)
     assert mh.quorum_met.all()
     assert (mh.participated == M).all()
     np.testing.assert_array_equal(mh.attempted, mh.delivered)
@@ -60,12 +50,8 @@ def test_sync_anchor_bitwise_int8(bundle, backend):
     kernels must reproduce the fused step's bits through EF residuals."""
     o = opt.make("chb", bundle.alpha_paper, M, quantize="int8",
                  backend=backend)
-    hist = simulator.run(o, bundle.task, 12)
-    mh = run_mesh(o, bundle.task, 12)
-    np.testing.assert_array_equal(np.asarray(hist.objective), mh.objective)
-    np.testing.assert_array_equal(
-        np.asarray(hist.mask).astype(np.int8), mh.mask)
-    _leaves_equal(hist.final_params, mh.final_params)
+    exactness.assert_bitwise(simulator.run(o, bundle.task, 12),
+                             run_mesh(o, bundle.task, 12))
 
 
 def test_donation_is_bit_identical(bundle):
@@ -75,10 +61,7 @@ def test_donation_is_bit_identical(bundle):
     sc = MeshScenario(participation=0.7, loss_prob=0.3, quorum=0.6, seed=5)
     plain = run_mesh(o, bundle.task, 15, scenario=sc)
     donated = run_mesh(o, bundle.task, 15, scenario=sc, donate=True)
-    np.testing.assert_array_equal(plain.objective, donated.objective)
-    np.testing.assert_array_equal(plain.mask, donated.mask)
-    np.testing.assert_array_equal(plain.quorum_met, donated.quorum_met)
-    _leaves_equal(plain.final_params, donated.final_params)
+    exactness.assert_bitwise(plain, donated)
 
 
 def test_bake_data_off_is_allclose_not_required_bitwise(bundle):
@@ -89,10 +72,7 @@ def test_bake_data_off_is_allclose_not_required_bitwise(bundle):
     sc = MeshScenario(participation=0.8, loss_prob=0.1, seed=2)
     baked = run_mesh(o, bundle.task, 12, scenario=sc)
     unbaked = run_mesh(o, bundle.task, 12, scenario=sc, bake_data=False)
-    np.testing.assert_array_equal(baked.mask, unbaked.mask)
-    np.testing.assert_array_equal(baked.participated, unbaked.participated)
-    np.testing.assert_allclose(baked.objective, unbaked.objective,
-                               rtol=1e-12)
+    exactness.assert_close_run(baked, unbaked, "argument_data")
 
 
 def test_scenario_draws_replay_exactly(bundle):
@@ -102,8 +82,7 @@ def test_scenario_draws_replay_exactly(bundle):
     sc = MeshScenario(participation=0.6, loss_prob=0.25, seed=11)
     a = run_mesh(o, bundle.task, 10, scenario=sc)
     b = run_mesh(o, bundle.task, 10, scenario=sc)
-    np.testing.assert_array_equal(a.mask, b.mask)
-    np.testing.assert_array_equal(a.objective, b.objective)
+    exactness.assert_bitwise(a, b)
     # and a different seed actually changes the draws
     c = run_mesh(o, bundle.task, 10,
                  scenario=MeshScenario(participation=0.6, loss_prob=0.25,
@@ -174,8 +153,8 @@ def test_collect_metrics_merges_to_simulator_bag(bundle):
               "step_sqnorm"):
         sim_series = np.asarray(hist.metrics[k])
         mesh_series = np.asarray([bag[k] for bag in mh.metrics])
-        np.testing.assert_allclose(mesh_series, sim_series, rtol=1e-12,
-                                   err_msg=k)
+        exactness.assert_close(mesh_series, sim_series, "mesh_metrics",
+                               err_msg=k)
 
 
 def test_rejects_non_composed_and_adaptive(bundle):

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import exactness
 from repro import fed
 from repro.core import baselines, simulator
 from repro.core.quantize import payload_bytes_dense
@@ -30,11 +31,7 @@ def test_sync_mode_reproduces_simulator(linreg, algo):
     cfg = baselines.ALGORITHMS[algo](linreg.alpha_paper, 5)
     ref = simulator.run(cfg, linreg.task, 60)
     hist = fed.run_edge(cfg, linreg.task, fed.sync_config(5), 60)
-    np.testing.assert_allclose(hist.objective, np.asarray(ref.objective),
-                               rtol=1e-9, atol=1e-12)
-    np.testing.assert_array_equal(hist.comm_cum, np.asarray(ref.comm_cum))
-    np.testing.assert_array_equal(hist.mask,
-                                  np.asarray(ref.mask).astype(np.int8))
+    exactness.assert_close_run(hist, ref, "event_runtime")
 
 
 def test_sync_mode_reproduces_simulator_int8(linreg):
@@ -43,9 +40,7 @@ def test_sync_mode_reproduces_simulator_int8(linreg):
                               quantize="int8")
     ref = simulator.run(cfg, linreg.task, 60)
     hist = fed.run_edge(cfg, linreg.task, fed.sync_config(5), 60)
-    np.testing.assert_allclose(hist.objective, np.asarray(ref.objective),
-                               rtol=1e-9, atol=1e-12)
-    np.testing.assert_array_equal(hist.comm_cum, np.asarray(ref.comm_cum))
+    exactness.assert_close_run(hist, ref, "event_runtime")
 
 
 def test_sync_mode_nn_task():
@@ -54,9 +49,7 @@ def test_sync_mode_nn_task():
     cfg = baselines.chb(0.02, 4)
     ref = simulator.run(cfg, b.task, 25)
     hist = fed.run_edge(cfg, b.task, fed.sync_config(4), 25)
-    np.testing.assert_allclose(hist.objective, np.asarray(ref.objective),
-                               rtol=1e-8)
-    np.testing.assert_array_equal(hist.comm_cum, np.asarray(ref.comm_cum))
+    exactness.assert_close_run(hist, ref, "event_runtime_pytree")
 
 
 # ----------------------------------------------------------- channel semantics

@@ -3,7 +3,7 @@
 Pins the observability contracts:
 
   * **bit-exactness**: metrics-on runs are bit-identical to metrics-off
-    runs on both backends — same golden hex fingerprints as
+    runs on both backends, and reproduce the same pin as
     tests/test_backend.py;
   * **zero extra compiles**: collecting metrics through ``run_sweep``
     neither changes the partition keys nor adds compiled programs or
@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import exactness
 from repro import fed, obs, opt, sweep
 from repro.core import simulator
 from repro.core.accounting import MIB, CommStats
@@ -40,11 +41,6 @@ M = 5
 ITERS = 60
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# same setting + golden as tests/test_backend.py: chb, f32, 60 iters
-GOLDEN_CHB_F32 = ("0x1.107a260000000p+6", "0x1.0024fc0000000p+12",
-                  262, 262, "0x1.dc40000000000p-42",
-                  "0x1.a94328858133cp+1")
 
 
 @pytest.fixture(scope="module")
@@ -65,24 +61,15 @@ def task32(linreg):
     return _as_f32(linreg.task)
 
 
-def _fingerprint(h):
-    obj = np.asarray(h.objective)
-    fsq = float(sum(np.sum(np.square(np.asarray(x, np.float64)))
-                    for x in jax.tree_util.tree_leaves(h.final_params)))
-    return (float(obj[-1]).hex(), float(obj.sum()).hex(),
-            int(np.asarray(h.comm_cum)[-1]),
-            int(np.asarray(h.mask).sum()),
-            float(np.asarray(h.agg_grad_sqnorm)[-1]).hex(), fsq.hex())
-
-
 # ===================================================== bit-exactness anchor
 @pytest.mark.parametrize("backend", opt.BACKENDS)
 def test_metrics_on_matches_golden_fingerprint(linreg, task32, backend):
-    """Metrics ride alongside the state: the golden hex trajectory is
-    unchanged with collection on, on both backends."""
+    """Metrics ride alongside the state: the pinned run (the same as
+    tests/test_backend.py's, chb at f32 for 60 iterations) is unchanged
+    with collection on, on both backends."""
     o = opt.make("chb", linreg.alpha_paper, M, backend=backend)
     h = simulator.run(o, task32, ITERS, collect_metrics=True)
-    assert _fingerprint(h) == GOLDEN_CHB_F32
+    exactness.assert_matches_pin(h, exactness.PINS["f32/dense"])
     # and the bag itself came back as stacked (K,) series
     assert h.metrics and all(np.asarray(v).shape == (ITERS,)
                              for v in h.metrics.values())
@@ -99,15 +86,7 @@ def test_metrics_bit_identity_all_fields(linreg):
     o = opt.make("chb", linreg.alpha_paper, M, quantize="int8")
     h0 = simulator.run(o, linreg.task, ITERS)
     h1 = simulator.run(o, linreg.task, ITERS, collect_metrics=True)
-    for f in ("objective", "mask", "comm_cum", "agg_grad_sqnorm"):
-        np.testing.assert_array_equal(np.asarray(getattr(h0, f)),
-                                      np.asarray(getattr(h1, f)), err_msg=f)
-    for a, b in zip(jax.tree_util.tree_leaves(h0.final_params),
-                    jax.tree_util.tree_leaves(h1.final_params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    for a, b in zip(jax.tree_util.tree_leaves(h0.final_state.ghat),
-                    jax.tree_util.tree_leaves(h1.final_state.ghat)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    exactness.assert_bitwise(h0, h1)
 
 
 # =============================================== MetricBag content + hooks
@@ -217,8 +196,7 @@ def test_sweep_metrics_roundtrip_zero_extra_compiles(linreg, task32):
     assert on.counts.get("kernels/tree_delta_sqnorms") == 1
     # trajectories bit-identical, metrics only on the collecting run
     for i in range(len(res0)):
-        np.testing.assert_array_equal(res0.history(i).objective,
-                                      res1.history(i).objective)
+        exactness.assert_bitwise(res0.history(i), res1.history(i))
         assert res0.metrics(i) == {}
         bag = res1.metrics(i)
         assert np.asarray(bag["censor_rate"]).shape == (40,)
@@ -296,8 +274,7 @@ def test_fed_metrics_and_staleness(linreg):
                       runlog=log)
     assert h0.metrics == ()
     # metrics are observation only: trajectories unchanged
-    np.testing.assert_array_equal(h0.objective, h1.objective)
-    np.testing.assert_array_equal(h0.mask, h1.mask)
+    exactness.assert_bitwise(h0, h1)
     bag = h1.metrics
     assert np.asarray(bag["censor_rate"]).shape == (25,)
     # sync anchor: nothing is ever late or dropped

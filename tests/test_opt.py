@@ -1,11 +1,10 @@
 """repro.opt: the composable optimizer protocol.
 
-Pins (1) bit-exact golden trajectories of every legacy composition against
-fingerprints recorded from the pre-redesign ``chb.step`` (the hex values
-below were produced by the monolithic implementation at commit 10c3388),
-(2) registry round-trips and error behavior, (3) the deprecation shims,
-(4) csgd — a pure composition — end-to-end through simulator, fed runtime,
-and sweep, and (5) censor-mask properties (hypothesis).
+Pins (1) the trajectory of every legacy composition against the runs the
+pre-redesign monolithic ``chb.step`` recorded (``exactness.PINS``), (2)
+registry round-trips and error behavior, (3) the deprecation shims, (4)
+csgd — a pure composition — end-to-end through simulator, fed runtime, and
+sweep, and (5) censor-mask properties (hypothesis).
 """
 import json
 import warnings
@@ -18,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import exactness
 from repro import fed, opt, sweep
 from repro.core import baselines, chb, simulator
 from repro.data import paper_tasks
@@ -28,77 +28,64 @@ def linreg():
     return paper_tasks.make_linear_regression(m=5, n_per=30, d=20, seed=0)
 
 
-def _fingerprint(o, task, num_iters):
-    h = simulator.run(o, task, num_iters)
-    obj = np.asarray(h.objective)
-    fsq = float(sum(np.sum(np.square(np.asarray(x)))
-                    for x in jax.tree_util.tree_leaves(h.final_params)))
-    return (float(obj[-1]).hex(), float(obj.sum()).hex(),
-            int(np.asarray(h.comm_cum)[-1]),
-            int(np.asarray(h.mask).sum()),
-            float(np.asarray(h.agg_grad_sqnorm)[-1]).hex(), fsq.hex())
-
-
-# Recorded from the pre-redesign monolithic chb.step (80 iters on the
-# m=5/n=30/d=20/seed=0 linreg task at alpha_paper; nn: 25 iters, alpha=.02).
-PRE_REDESIGN = {
-    "gd": ("0x1.107a2630170dep+6", "0x1.5565de3d49cdep+12", 400, 400,
-           "0x1.89217c0000000p-47", "0x1.a9432872d3e1dp+1"),
-    "hb": ("0x1.107a2630170dep+6", "0x1.554a72a2ae846p+12", 400, 400,
-           "0x1.bf00000000000p-99", "0x1.a9432904593dep+1"),
-    "lag": ("0x1.107a2630170dfp+6", "0x1.55624996ff56bp+12", 318, 318,
-            "0x1.b7ba9e0000000p-49", "0x1.a94328ba0160bp+1"),
-    "chb": ("0x1.107a2630170dfp+6", "0x1.554b25e02a552p+12", 322, 322,
-            "0x1.4975000000000p-90", "0x1.a9432904593e7p+1"),
-    "chb_int8": ("0x1.107a2630170dfp+6", "0x1.554b482e14e77p+12", 322, 322,
-                 "0x1.74d9900000000p-90", "0x1.a9432904593e6p+1"),
-    "chb_per_tensor": ("0x1.107a2630170dfp+6", "0x1.554b25e02a552p+12",
-                       339, 339, "0x1.2fe2a80000000p-89",
-                       "0x1.a9432904593e2p+1"),
-    "adaptive": ("0x1.107d098b8dcacp+6", "0x1.564a627d34fcep+12", 83, 83,
-                 "0x1.4ab7740000000p-5", "0x1.aa4b7667b4258p+1"),
-    "nn_chb": ("0x1.403883a4462c4p+2", "0x1.94b4c291e8686p+8", 40, 40,
-               "0x1.61d8d00000000p+2", "0x1.1a697c350cf04p+5"),
-}
-
-ALPHA_PAPER_HEX = "0x1.406a1a2d8bd52p-4"
-
-
 def test_task_alpha_unchanged(linreg):
-    """The goldens assume this task; if alpha moves, they mean nothing."""
-    assert float(linreg.alpha_paper).hex() == ALPHA_PAPER_HEX
+    """The pins assume this task; if alpha moves, they mean nothing."""
+    assert float(linreg.alpha_paper) == exactness.LINREG_ALPHA_PAPER
 
 
-# ------------------------------------------------- golden bit-exactness
+# ------------------------------------------------------ pinned trajectories
 @pytest.mark.parametrize("name", ["gd", "hb", "lag", "chb"])
 def test_registry_matches_pre_redesign_step(linreg, name):
-    got = _fingerprint(opt.make(name, linreg.alpha_paper, 5),
-                       linreg.task, 80)
-    assert got == PRE_REDESIGN[name]
+    h = simulator.run(opt.make(name, linreg.alpha_paper, 5), linreg.task, 80,
+                      collect_metrics=True)
+    exactness.assert_matches_pin(h, exactness.PINS[name])
 
 
 def test_int8_composition_matches_pre_redesign(linreg):
     o = opt.make("chb", linreg.alpha_paper, 5, quantize="int8")
-    assert _fingerprint(o, linreg.task, 80) == PRE_REDESIGN["chb_int8"]
+    exactness.assert_matches_pin(
+        simulator.run(o, linreg.task, 80, collect_metrics=True),
+        exactness.PINS["chb_int8"])
 
 
 def test_per_tensor_composition_matches_pre_redesign(linreg):
     o = opt.make("chb", linreg.alpha_paper, 5, granularity="per_tensor")
-    assert _fingerprint(o, linreg.task, 80) == \
-        PRE_REDESIGN["chb_per_tensor"]
+    exactness.assert_matches_pin(
+        simulator.run(o, linreg.task, 80, collect_metrics=True),
+        exactness.PINS["chb_per_tensor"])
 
 
 def test_adaptive_composition_matches_pre_redesign(linreg):
     o = opt.ComposedOptimizer(
         censor=opt.AdaptiveCensor(0.25), transport=opt.DenseTransport(),
         server=opt.HeavyBall(linreg.alpha_paper, 0.4), num_workers=5)
-    assert _fingerprint(o, linreg.task, 80) == PRE_REDESIGN["adaptive"]
+    exactness.assert_matches_pin(
+        simulator.run(o, linreg.task, 80, collect_metrics=True),
+        exactness.PINS["adaptive"])
 
 
 def test_pytree_task_matches_pre_redesign():
-    bn = paper_tasks.make_neural_network(m=4, n_per=40, d=8, hidden=6)
-    assert _fingerprint(opt.make("chb", 0.02, 4), bn.task, 25) == \
-        PRE_REDESIGN["nn_chb"]
+    # the pin's init was drawn before jax made threefry partitionable by
+    # default; the same key draws another init in that mode
+    with jax.threefry_partitionable(False):
+        bn = paper_tasks.make_neural_network(m=4, n_per=40, d=8, hidden=6)
+    h = simulator.run(opt.make("chb", 0.02, 4), bn.task, 25,
+                      collect_metrics=True)
+    exactness.assert_matches_pin(h, exactness.PINS["nn_chb"])
+
+
+@pytest.mark.parametrize("kw,pin", [
+    ({}, "chb_per_tensor"), ({"granularity": "per_tensor"}, "chb"),
+    ({}, "chb_int8"), ({"quantize": "int8"}, "chb"),
+])
+def test_pin_rejects_a_neighbouring_route(linreg, kw, pin):
+    """A pin tells its route from the nearest other one: global from
+    per-tensor censoring (the uplinks part at iteration 42, before either
+    floor), dense from int8 (the uplink bytes)."""
+    h = simulator.run(opt.make("chb", linreg.alpha_paper, 5, **kw),
+                      linreg.task, 80, collect_metrics=True)
+    with pytest.raises(AssertionError):
+        exactness.assert_matches_pin(h, exactness.PINS[pin])
 
 
 # ------------------------------------------------------ deprecation shims
@@ -120,16 +107,8 @@ def test_baselines_warn_and_match_registry_bitwise(linreg, name):
     h_facade = simulator.run(cfg, linreg.task, 60)
     h_built = simulator.run(built, linreg.task, 60)
     h_reg = simulator.run(reg, linreg.task, 60)
-    for a, b in ((h_facade, h_reg), (h_built, h_reg)):
-        np.testing.assert_array_equal(np.asarray(a.objective),
-                                      np.asarray(b.objective))
-        np.testing.assert_array_equal(np.asarray(a.mask),
-                                      np.asarray(b.mask))
-        np.testing.assert_array_equal(np.asarray(a.comm_cum),
-                                      np.asarray(b.comm_cum))
-        for x, y in zip(jax.tree_util.tree_leaves(a.final_params),
-                        jax.tree_util.tree_leaves(b.final_params)):
-            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    exactness.assert_bitwise(h_facade, h_reg)
+    exactness.assert_bitwise(h_built, h_reg)
 
 
 def test_legacy_step_entrypoint_still_works(linreg):
@@ -264,10 +243,7 @@ def test_run_sweep_accepts_gd_server_base(linreg):
             censor=opt.Eq8Censor(p.eps1), transport=opt.DenseTransport(),
             server=opt.HeavyBall(p.alpha, p.beta), num_workers=5),
             linreg.task, 60)
-        np.testing.assert_array_equal(np.asarray(hist.objective),
-                                      np.asarray(ref.objective))
-        np.testing.assert_array_equal(np.asarray(hist.mask),
-                                      np.asarray(ref.mask))
+        exactness.assert_bitwise(hist, ref)
 
 
 def test_run_sweep_keeps_stochastic_base_censor(linreg):
@@ -281,9 +257,8 @@ def test_run_sweep_keeps_stochastic_base_censor(linreg):
     for spec in res.specs:
         assert spec["censor"]["kind"] == "stochastic"
         assert spec["censor"]["tau0"] == 1e3
-    ref = simulator.run(base, linreg.task, 40)
-    np.testing.assert_array_equal(np.asarray(res.histories[0].mask),
-                                  np.asarray(ref.mask))
+    exactness.assert_bitwise(res.histories[0],
+                             simulator.run(base, linreg.task, 40))
     # ...but a VARYING eps axis over such a base would be silently
     # ignored trajectory-wise — run_sweep must refuse it loudly
     bad = [sweep.GridPoint(alpha=a, eps1=0.1),
@@ -327,11 +302,7 @@ def test_csgd_fed_sync_anchor_matches_simulator(linreg):
     o = _csgd(linreg.alpha_paper, 5, tau0=1e3, decay=0.99)
     ref = simulator.run(o, linreg.task, 60)
     hist = fed.run_edge(o, linreg.task, fed.sync_config(5), 60)
-    np.testing.assert_array_equal(hist.mask,
-                                  np.asarray(ref.mask).astype(np.int8))
-    np.testing.assert_array_equal(hist.comm_cum, np.asarray(ref.comm_cum))
-    np.testing.assert_allclose(hist.objective, np.asarray(ref.objective),
-                               rtol=1e-9, atol=1e-12)
+    exactness.assert_close_run(hist, ref, "event_runtime")
 
 
 def test_named_point_defaults_use_builder_defaults(linreg):
@@ -347,11 +318,9 @@ def test_named_point_defaults_use_builder_defaults(linreg):
     assert spec0["censor"]["kind"] == "eq8" and \
         spec0["censor"]["eps1"] > 0           # paper default applied
     assert spec0["server"] == {"kind": "hb", "alpha": float(a), "beta": 0.4}
-    ref = simulator.run(opt.make("chb", a, 5), linreg.task, 60)
-    np.testing.assert_array_equal(np.asarray(res.histories[0].objective),
-                                  np.asarray(ref.objective))
-    np.testing.assert_array_equal(np.asarray(res.histories[0].mask),
-                                  np.asarray(ref.mask))
+    exactness.assert_bitwise(
+        res.histories[0], simulator.run(opt.make("chb", a, 5), linreg.task,
+                                        60))
     # the explicitly-set beta point really used beta=0.2
     assert res.specs[1]["server"]["beta"] == 0.2
 
@@ -371,12 +340,7 @@ def test_csgd_sweep_partition_bit_exact(linreg):
     for p, hist in zip(pts[1:], res.histories[1:]):
         ref = simulator.run(
             opt.make("csgd", p.alpha, 5, tau0=p.eps1), linreg.task, 80)
-        np.testing.assert_array_equal(np.asarray(hist.objective),
-                                      np.asarray(ref.objective))
-        np.testing.assert_array_equal(np.asarray(hist.mask),
-                                      np.asarray(ref.mask))
-        np.testing.assert_array_equal(np.asarray(hist.comm_cum),
-                                      np.asarray(ref.comm_cum))
+        exactness.assert_bitwise(hist, ref)
 
 
 def test_csgd_fed_scenario_sweep_ideal_anchor(linreg):
@@ -437,10 +401,8 @@ def test_minimal_protocol_optimizer_runs_in_simulator(linreg):
 
     inner = opt.make("chb", linreg.alpha_paper, 5)
     wrapped = Wrapped(inner)
-    hist = simulator.run(wrapped, linreg.task, 40)
-    ref = simulator.run(inner, linreg.task, 40)
-    np.testing.assert_array_equal(np.asarray(hist.objective),
-                                  np.asarray(ref.objective))
+    exactness.assert_bitwise(simulator.run(wrapped, linreg.task, 40),
+                             simulator.run(inner, linreg.task, 40))
     with pytest.raises(TypeError, match="ComposedOptimizer"):
         fed.run_edge(wrapped, linreg.task, fed.sync_config(5), 5)
     with pytest.raises(TypeError, match="ComposedOptimizer"):
@@ -512,7 +474,7 @@ def test_censor_mask_monotone_in_eps1_concrete():
 
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
     HAVE_HYPOTHESIS = True
 except ImportError:              # pragma: no cover - CI installs hypothesis
     HAVE_HYPOTHESIS = False
@@ -523,6 +485,10 @@ if HAVE_HYPOTHESIS:
     @given(dsq=st.lists(st.floats(0.0, 1e6), min_size=1, max_size=8),
            ssq=st.floats(0.0, 1e6),
            e1=st.floats(0.0, 1e3), e2=st.floats(0.0, 1e3))
+    # a subnormal threshold, which XLA flushes to zero when it is traced
+    @example(dsq=[0.0], ssq=0.0, e1=0.0, e2=5e-324)
+    # a delta just above the threshold: both branches must compute it alike
+    @example(dsq=[1.005], ssq=1.0, e1=0.0, e2=1.0)
     def test_property_censor_mask_monotone_in_eps1(dsq, ssq, e1, e2):
         """Raising eps1 can only censor MORE workers (eq. 8 is a one-sided
         threshold), for static and traced thresholds alike."""

@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
+import exactness
 from repro import opt
 from repro.configs import get
 from repro.data import paper_tasks
@@ -199,12 +200,9 @@ def test_run_mesh_setup_counts_cohort_rows(draws, want):
 def test_run_mesh_same_with_profiler_on_and_off(mesh_run, mesh_traced):
     traced, _ = mesh_traced
     plain = mesh_run()
-    for f in ("mask", "participated", "attempted", "delivered",
-              "quorum_met", "comm_cum", "delivered_cum", "bytes_cum"):
-        np.testing.assert_array_equal(getattr(traced, f), getattr(plain, f))
-    for f in ("objective", "agg_grad_sqnorm", "energy_cum", "wall_clock"):
-        np.testing.assert_allclose(getattr(traced, f), getattr(plain, f),
-                                   rtol=1e-6)
+    exactness.assert_close_run(
+        traced, plain, "profiler",
+        floats=("objective", "agg_grad_sqnorm", "energy_cum", "wall_clock"))
 
 
 # ------------------------------------------------------------------- train
@@ -252,5 +250,5 @@ def test_train_same_with_profiler_on_and_off(train_run, train_traced):
     np.testing.assert_array_equal(np.asarray(t_state.comm.uplink_count),
                                   np.asarray(p_state.comm.uplink_count))
     assert [h["comms"] for h in t_hist] == [h["comms"] for h in p_hist]
-    np.testing.assert_allclose([h["loss"] for h in t_hist],
-                               [h["loss"] for h in p_hist], rtol=1e-6)
+    exactness.assert_close([h["loss"] for h in t_hist],
+                           [h["loss"] for h in p_hist], "profiler")

@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import exactness
 from repro import sweep
 from repro.core import baselines, chb, simulator
 from repro.core.censoring import paper_eps1
@@ -28,21 +29,6 @@ def linreg():
 def _task_factory(seed, m):
     return paper_tasks.make_linear_regression(
         m=m, n_per=30, d=20, seed=seed).task
-
-
-def _assert_history_equal(hist, ref):
-    """Bitwise trajectory equality: objective, comms, masks, final params."""
-    np.testing.assert_array_equal(np.asarray(hist.objective),
-                                  np.asarray(ref.objective))
-    np.testing.assert_array_equal(np.asarray(hist.comm_cum),
-                                  np.asarray(ref.comm_cum))
-    np.testing.assert_array_equal(np.asarray(hist.mask),
-                                  np.asarray(ref.mask))
-    np.testing.assert_array_equal(np.asarray(hist.agg_grad_sqnorm),
-                                  np.asarray(ref.agg_grad_sqnorm))
-    for a, b in zip(jax.tree_util.tree_leaves(hist.final_params),
-                    jax.tree_util.tree_leaves(ref.final_params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ------------------------------------------------------------------- grid
@@ -87,7 +73,7 @@ def test_sweep_matches_per_point_run_exactly(linreg):
     for p, hist in zip(points, res.histories):
         cfg = chb.FedOptConfig(alpha=p.alpha, beta=p.beta, eps1=p.eps1,
                                num_workers=5)
-        _assert_history_equal(hist, simulator.run(cfg, linreg.task, 120))
+        exactness.assert_bitwise(hist, simulator.run(cfg, linreg.task, 120))
 
 
 def test_sweep_int8_quantized_path_exact(linreg):
@@ -105,7 +91,7 @@ def test_sweep_int8_quantized_path_exact(linreg):
     for p, hist in zip(points, res.histories):
         cfg = chb.FedOptConfig(alpha=p.alpha, beta=p.beta, eps1=p.eps1,
                                num_workers=5, quantize=p.quantize)
-        _assert_history_equal(hist, simulator.run(cfg, linreg.task, 100))
+        exactness.assert_bitwise(hist, simulator.run(cfg, linreg.task, 100))
     # quantized transmissions ship ~8x fewer bytes (f64 -> int8 + scale)
     assert res.uplink_bytes[1] < 0.25 * res.uplink_bytes[0]
 
@@ -122,7 +108,7 @@ def test_sweep_seed_axis_exact():
         cfg = chb.FedOptConfig(alpha=p.alpha, beta=p.beta, eps1=p.eps1,
                                num_workers=5)
         ref = simulator.run(cfg, _task_factory(p.seed, 5), 60)
-        _assert_history_equal(hist, ref)
+        exactness.assert_bitwise(hist, ref)
 
 
 def test_sweep_seed_axis_requires_factory(linreg):
@@ -154,7 +140,7 @@ def test_sweep_per_tensor_granularity_exact(linreg):
         ref = simulator.run(
             opt.make("chb", p.alpha, 5, beta=p.beta, eps1=p.eps1,
                      granularity="per_tensor"), linreg.task, 80)
-        _assert_history_equal(hist, ref)
+        exactness.assert_bitwise(hist, ref)
     # per-tensor masks really differ from global censoring on this grid
     ref_global = simulator.run(opt.make("chb", a, 5, eps1=eps),
                                linreg.task, 80)
@@ -175,7 +161,7 @@ def test_sweep_float32_task_exact_under_x64():
     res = sweep.run_sweep(
         [sweep.GridPoint(alpha=cfg.alpha, beta=cfg.beta, eps1=cfg.eps1)],
         task=task32, num_iters=200)
-    _assert_history_equal(res.history(0), simulator.run(cfg, task32, 200))
+    exactness.assert_bitwise(res.history(0), simulator.run(cfg, task32, 200))
 
 
 def test_transmit_mask_traced_eps_matches_static_at_f32_boundary():
@@ -205,7 +191,7 @@ def test_sweep_nn_pytree_task():
     for p, hist in zip(pts, res.histories):
         c = chb.FedOptConfig(alpha=p.alpha, beta=p.beta, eps1=p.eps1,
                              num_workers=4)
-        _assert_history_equal(hist, simulator.run(c, b.task, 25))
+        exactness.assert_bitwise(hist, simulator.run(c, b.task, 25))
 
 
 def test_sweep_vectorized_mode_close(linreg):
@@ -221,9 +207,7 @@ def test_sweep_vectorized_mode_close(linreg):
         c = chb.FedOptConfig(alpha=p.alpha, beta=p.beta, eps1=p.eps1,
                              num_workers=5)
         ref = simulator.run(c, linreg.task, 80)
-        np.testing.assert_allclose(np.asarray(hist.objective),
-                                   np.asarray(ref.objective),
-                                   rtol=1e-6, atol=1e-8)
+        exactness.assert_close(hist.objective, ref.objective, "vectorized")
 
 
 def test_traced_structural_fields_raise(linreg):

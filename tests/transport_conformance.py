@@ -7,8 +7,9 @@ backend × surface × spec matrix can't silently grow an uncovered cell.
 
 The contract, per transport:
 
-  * reference ↔ pallas **bit-identity** at f32 and f64 on the golden
-    linreg task and on a pytree (NN) task with matrix leaves;
+  * reference ↔ pallas compute the same run (``exactness.assert_same_run``)
+    at f32 and f64 on the pinned linreg task and on a pytree (NN) task
+    with matrix leaves;
   * the row entry points (``prepare_row``/``encode_row``/
     ``feedback_row``, what ``repro.fed`` drives per client) agree with
     the matching worker slice of the batched step;
@@ -43,6 +44,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import exactness
 from repro import opt, sweep
 from repro.core import simulator
 from repro.core.accounting import CommStats
@@ -96,15 +98,6 @@ def task32(linreg):
     return _as_f32(linreg.task)
 
 
-def _assert_histories_equal(h1, h2):
-    for f in ("objective", "mask", "comm_cum", "agg_grad_sqnorm"):
-        np.testing.assert_array_equal(np.asarray(getattr(h1, f)),
-                                      np.asarray(getattr(h2, f)), err_msg=f)
-    for a, b in zip(jax.tree_util.tree_leaves(h1.final_params),
-                    jax.tree_util.tree_leaves(h2.final_params)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
 def _chb(alpha, kind, backend="reference"):
     return opt.make("chb", alpha, M, transport=make_transport(kind),
                     backend=backend)
@@ -123,10 +116,10 @@ def test_unknown_transport_kind_raises():
         sweep.ConfigGrid(alpha=[0.1], quantize=["int4"])
 
 
-# --------------------------------------------------- backend bit-identity
+# ------------------------------------------------------ backend parity
 @pytest.mark.parametrize("kind", KINDS)
 def test_backend_bitwise_f32(linreg, task32, kind):
-    _assert_histories_equal(
+    exactness.assert_same_run(
         simulator.run(_chb(linreg.alpha_paper, kind), task32, ITERS),
         simulator.run(_chb(linreg.alpha_paper, kind, "pallas"), task32,
                       ITERS))
@@ -135,7 +128,7 @@ def test_backend_bitwise_f32(linreg, task32, kind):
 @pytest.mark.parametrize("kind", KINDS)
 def test_backend_bitwise_f64(linreg, kind):
     require_x64()
-    _assert_histories_equal(
+    exactness.assert_same_run(
         simulator.run(_chb(linreg.alpha_paper, kind), linreg.task, ITERS),
         simulator.run(_chb(linreg.alpha_paper, kind, "pallas"), linreg.task,
                       ITERS))
@@ -144,14 +137,14 @@ def test_backend_bitwise_f64(linreg, kind):
 @pytest.mark.parametrize("kind", KINDS)
 def test_pytree_task_bitwise(kind):
     """Matrix leaves (the NN task) exercise the low-rank factor path and
-    per-leaf top-k selection; both backends must still agree bitwise."""
+    per-leaf top-k selection; both backends must still compute one run."""
     bn = paper_tasks.make_neural_network(m=4, n_per=40, d=8, hidden=6)
     t32 = _as_f32(bn.task)
     t = make_transport(kind)
     o_ref = opt.make("chb", 0.02, 4, transport=t)
     o_pal = opt.make("chb", 0.02, 4, transport=t, backend="pallas")
-    _assert_histories_equal(simulator.run(o_ref, t32, 25),
-                            simulator.run(o_pal, t32, 25))
+    exactness.assert_same_run(simulator.run(o_ref, t32, 25),
+                              simulator.run(o_pal, t32, 25))
 
 
 # ------------------------------------------------------ row vs batched
@@ -231,8 +224,8 @@ def test_ef_residual_telescopes(kind):
                 if t.exact_residual:
                     np.testing.assert_array_equal(q[tx] + e1[tx], p[tx])
                 else:
-                    np.testing.assert_allclose(q[tx] + e1[tx], p[tx],
-                                               rtol=1e-5, atol=1e-6)
+                    exactness.assert_close(q[tx] + e1[tx], p[tx],
+                                           "lossy_residual")
                 np.testing.assert_array_equal(e1[~tx], e0[~tx])
         err = new_err
 
@@ -281,7 +274,7 @@ def test_metrics_read_only_bit_identity(linreg, task32, kind):
     o = _chb(linreg.alpha_paper, kind)
     h_off = simulator.run(o, task32, 25)
     h_on = simulator.run(o, task32, 25, collect_metrics=True)
-    _assert_histories_equal(h_off, h_on)
+    exactness.assert_bitwise(h_off, h_on)
     if make_transport(kind).stateful:
         key = f"transport/{kind}/ef_residual_sqnorm"
         assert key in h_on.metrics, sorted(h_on.metrics)
@@ -308,12 +301,9 @@ def test_sweep_one_program_and_base_transport_survives(linreg, task32, kind):
         o = base.with_hparams(alpha=pt.alpha, beta=pt.beta, eps1=pt.eps1)
         h = simulator.run(o, task, 25)
         if x64_enabled():
-            np.testing.assert_array_equal(
-                np.asarray(h.objective), np.asarray(res.history(i).objective))
+            exactness.assert_bitwise(h, res.history(i))
         else:
-            np.testing.assert_allclose(
-                np.asarray(h.objective), np.asarray(res.history(i).objective),
-                rtol=1e-5)
+            exactness.assert_close_run(h, res.history(i), "traced_f32")
 
 
 # ----------------------------------------------------- kernel-level pins
@@ -371,17 +361,18 @@ def test_lowrank_kernel_matches_oracle(dtype):
 # ------------------------------------------------- fused-step conformance
 # The one-sweep megakernel (kernels/fused_step.py) is the default pallas
 # route for dense and int8+EF; topk/lowrank keep the staged chain. Every
-# kind is enrolled here: the trajectory tests pin fused == force_staged()
-# bit-for-bit (a no-op for the staged kinds, a real contract for the
-# fused ones), and the kernel pins compare the megakernel against the
-# staged kernel chain AND the ref.py oracle, element-for-element.
+# kind is enrolled here: the trajectory tests hold fused and
+# force_staged() to one run (a no-op for the staged kinds, a real
+# contract for the fused ones), and the kernel pins compare the
+# megakernel against the staged kernel chain AND the ref.py oracle,
+# element-for-element.
 @pytest.mark.parametrize("kind", KINDS)
 def test_fused_matches_staged_trajectory_f32(linreg, task32, kind):
     o = _chb(linreg.alpha_paper, kind, "pallas")
     h_fused = simulator.run(o, task32, ITERS)
     with fused_step.force_staged():
         h_staged = simulator.run(o, task32, ITERS)
-    _assert_histories_equal(h_fused, h_staged)
+    exactness.assert_same_run(h_fused, h_staged)
 
 
 @pytest.mark.parametrize("kind", ["dense", "int8"])
@@ -391,16 +382,16 @@ def test_fused_matches_staged_trajectory_f64(linreg, kind):
     h_fused = simulator.run(o, linreg.task, ITERS)
     with fused_step.force_staged():
         h_staged = simulator.run(o, linreg.task, ITERS)
-    _assert_histories_equal(h_fused, h_staged)
+    exactness.assert_same_run(h_fused, h_staged)
 
 
 @pytest.mark.parametrize("kind", ["dense", "int8"])
 def test_fused_metrics_read_only(linreg, task32, kind):
     """Metrics collection must stay read-only on the fused route too."""
     o = _chb(linreg.alpha_paper, kind, "pallas")
-    _assert_histories_equal(simulator.run(o, task32, 25),
-                            simulator.run(o, task32, 25,
-                                          collect_metrics=True))
+    exactness.assert_bitwise(simulator.run(o, task32, 25),
+                             simulator.run(o, task32, 25,
+                                           collect_metrics=True))
 
 
 def _fused_inputs(dtype, m=5, seed=4):
@@ -506,8 +497,8 @@ def test_fused_int8_kernel_matches_staged_and_oracle(dtype):
     if dtype == "float64":
         np.testing.assert_array_equal(recon, np.asarray(pending)[tx])
     else:
-        np.testing.assert_allclose(recon, np.asarray(pending)[tx],
-                                   rtol=1e-6, atol=1e-7)
+        exactness.assert_close(recon, np.asarray(pending)[tx],
+                               "fused_residual_f32")
 
 
 @pytest.mark.parametrize("dtype", ["float32", "float64"])
