@@ -362,6 +362,15 @@ def run_mesh(cfg, task: FedTask, num_rounds: int, *,
         way, and the K-invariance anchor (b) holds in both modes.
     Returns:
       A ``MeshHistory``.
+
+    The host reads each round's results one round late, in one batched
+    fetch: it dispatches round k, then fetches round k-1's server scalars,
+    slowest compute times, mask rows and bags with one ``jax.device_get``
+    and accounts for them, so the devices have round k queued meanwhile.
+    The last round's results are drained the same way before the call
+    returns. Only those host-bound outputs are held across the lag, never
+    a shard's state, which the next round takes (donated under
+    ``donate``); the history is the one a serial loop builds.
     """
     with profile.annotate("run_mesh"):
         opt = as_optimizer(cfg)
@@ -479,77 +488,93 @@ def run_mesh(cfg, task: FedTask, num_rounds: int, *,
         mask_rows: list[np.ndarray] = []
         bags: list[dict] = []
 
+        def dispatch(k):
+            """Enqueue round k; return what the host reads of it, with
+            its transfers started: the server's seven scalars, then per
+            shard its slowest client's compute time, its mask rows and its
+            bag. Never a shard's state, which round k+1 takes (donated)."""
+            nonlocal states, params_rep, prev_rep
+            params_views = per_device_views(params_rep, mesh)
+            outs = [run_shard(i, states[i], params_views[i], k)
+                    for i in range(k_shards)]
+            stacked = stack_shards([o[1] for o in outs], mesh)
+            (params_rep, prev_rep, *scalars) = server_prog(
+                stacked, params_rep, prev_rep)
+            # shard states carry theta^{k-1} for the next eq.-(8) step
+            # norm; quorum may have frozen it, so overwrite from the
+            # server's (replicated) new_prev. Copy under donation: the raw
+            # per-device views alias prev_rep's buffers, which the next
+            # round would donate away while server_round still needs them.
+            prev_views = per_device_views(prev_rep, mesh)
+            states = [o[0]._replace(prev_params=copy_tree(pv) if donate
+                                    else pv)
+                      for o, pv in zip(outs, prev_views)]
+            kept = (scalars, [o[3] for o in outs],
+                    [o[2] for o in outs] if collect_mask else [],
+                    [o[4] for o in outs] if collect_metrics else [])
+            for x in jax.tree_util.tree_leaves(kept):
+                x.copy_to_host_async()
+            return kept
+
+        def sync(k, kept):
+            """Round k's host values, in one batched fetch; ``ahead`` says
+            whether the round was still running, so the chip had the next
+            round queued while the host waited."""
+            leaves = jax.tree_util.tree_leaves(kept)
+            ahead = int(not all(x.is_ready() for x in leaves))
+            with profile.annotate("run_mesh/sync", round=k,
+                                  reads=len(leaves), fetches=1,
+                                  ahead=ahead):
+                return jax.device_get(kept)
+
+        def account(got):
+            nonlocal t, joules
+            ((met, loss, gsq, n_part, n_att, n_del, comp_j), walls,
+             shard_rows, shard_bags) = got
+            part_h, att_h = int(n_part), int(n_att)
+            # one shard's cohort is the round's: the blocks it took
+            chunks = {} if not cohort_rows or k_shards > 1 else {
+                "cohort_chunks": max(1, -(-part_h // cohort_rows))}
+            with profile.annotate("run_mesh/account", **chunks):
+                objective.append(float(loss))
+                gsq_hist.append(float(gsq))
+                met_hist.append(bool(met))
+                n_part_h.append(part_h)
+                n_att_h.append(att_h)
+                n_del_h.append(int(n_del))
+                t += (max(float(w) for w in walls)
+                      + (uplink_air if att_h else 0.0) + downlink_air)
+                wall.append(t)
+                joules += float(energy.round_energy(
+                    att_h, part_h, payload)) + float(comp_j)
+                energy_cum.append(joules)
+                if collect_mask:
+                    mask_rows.append(
+                        np.concatenate(shard_rows).astype(np.int8))
+                if collect_metrics:
+                    from ..obs.metrics import merge_shard_bags
+                    merged = merge_shard_bags(
+                        shard_bags, weights=[m_local] * k_shards)
+                    merged = {kk: float(np.asarray(v))
+                              for kk, v in merged.items()}
+                    merged["agg_grad_sqnorm"] = gsq_hist[-1]
+                    bags.append(merged)
+
+        # The readback runs one round late: round k's span dispatches
+        # round k, then reads round k-1's values in one batched fetch and
+        # accounts for them, so the chip has round k queued while the host
+        # waits; the last round's span drains its own. None of those
+        # values feeds a later round.
+        pending = None
         for k in range(num_rounds):
             with profile.step("run_mesh/round", k):
                 with profile.annotate("run_mesh/dispatch"):
-                    params_views = per_device_views(params_rep, mesh)
-                    outs = [run_shard(i, states[i], params_views[i], k)
-                            for i in range(k_shards)]
-                    states = [o[0] for o in outs]
-                    stacked = stack_shards([o[1] for o in outs], mesh)
-                    (params_rep, prev_rep, met, loss_sum, gsq, n_part, n_att,
-                     n_del, comp_j) = server_prog(stacked, params_rep,
-                                                  prev_rep)
-
-                    # shard states carry theta^{k-1} for the next eq.-(8)
-                    # step norm; quorum may have frozen it, so overwrite
-                    # from the server's (replicated) new_prev. Copy under
-                    # donation: the raw per-device views alias prev_rep's
-                    # buffers, which the next round would donate away while
-                    # server_round still needs them.
-                    prev_views = per_device_views(prev_rep, mesh)
-                    states = [
-                        st._replace(prev_params=copy_tree(pv) if donate
-                                    else pv)
-                        for st, pv in zip(states, prev_views)]
-
-                # the round's reads back to the host, one array at a time:
-                # 7 server scalars, then per shard its slowest client's
-                # compute time, its mask rows and each entry of its bag
-                reads = 7 + k_shards * (1 + int(collect_mask) + (
-                    len(outs[0][4]) if collect_metrics else 0))
-                with profile.annotate("run_mesh/sync", reads=reads):
-                    loss_h = float(loss_sum)
-                    gsq_h = float(gsq)
-                    met_h = bool(met)
-                    part_h, att_h, del_h = int(n_part), int(n_att), \
-                        int(n_del)
-                    comp_h = float(comp_j)
-                    slowest = max(float(o[3]) for o in outs)
-                    if collect_mask:
-                        shard_rows = [np.asarray(o[2]) for o in outs]
-                    if collect_metrics:
-                        shard_bags = [{kk: np.asarray(v)
-                                       for kk, v in o[4].items()}
-                                      for o in outs]
-
-                # one shard's cohort is the round's: the blocks it took
-                chunks = {} if not cohort_rows or k_shards > 1 else {
-                    "cohort_chunks": max(1, -(-part_h // cohort_rows))}
-                with profile.annotate("run_mesh/account", **chunks):
-                    objective.append(loss_h)
-                    gsq_hist.append(gsq_h)
-                    met_hist.append(met_h)
-                    n_part_h.append(part_h)
-                    n_att_h.append(att_h)
-                    n_del_h.append(del_h)
-                    t += (slowest + (uplink_air if att_h else 0.0)
-                          + downlink_air)
-                    wall.append(t)
-                    joules += float(energy.round_energy(
-                        att_h, part_h, payload)) + comp_h
-                    energy_cum.append(joules)
-                    if collect_mask:
-                        mask_rows.append(
-                            np.concatenate(shard_rows).astype(np.int8))
-                    if collect_metrics:
-                        from ..obs.metrics import merge_shard_bags
-                        merged = merge_shard_bags(
-                            shard_bags, weights=[m_local] * k_shards)
-                        merged = {kk: float(np.asarray(v))
-                                  for kk, v in merged.items()}
-                        merged["agg_grad_sqnorm"] = gsq_h
-                        bags.append(merged)
+                    kept = dispatch(k)
+                if pending is not None:
+                    account(sync(k - 1, pending))
+                pending = kept
+                if k == num_rounds - 1:
+                    account(sync(k, pending))
 
         with profile.annotate("run_mesh/history"):
             att = np.asarray(n_att_h, np.int64)

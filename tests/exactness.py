@@ -1,7 +1,7 @@
 """The exactness contract of the test suite, stated once.
 
 Every test that compares a run with another run, or with numbers recorded
-from an earlier run, goes through this module. It holds four kinds of
+from an earlier run, goes through this module. It holds five kinds of
 comparison.
 
 ``assert_same_run(h1, h2)`` — two programs that must compute the same run
@@ -21,6 +21,12 @@ comparison.
 ``assert_bitwise(h1, h2)`` — runs that must be identical in every field,
 diagnostics included: donation on and off, metrics on and off, a sweep's
 rows against per-point runs of the same backend, the int8 sync anchor.
+
+``assert_identical(h1, h2)`` — two histories of one run that only the
+host's schedule tells apart (``run_mesh`` against a serial loop of the
+same programs): every field bitwise and of one dtype, the wall-clock and
+energy models and the metric bags included, and a field left empty
+(``mask=None``) empty on both.
 
 ``assert_close_run(h1, h2, bound)`` — two computations of one run that
 are allowed to round differently (another gradient batching, traced
@@ -192,6 +198,21 @@ def assert_same_run(h1, h2):
 def assert_bitwise(h1, h2):
     """Every field bitwise, diagnostics included."""
     _compare(h1, h2, 0)
+
+
+def assert_identical(h1, h2):
+    """Every field of two histories of one type bitwise, dtypes and
+    empty fields included."""
+    assert type(h1) is type(h2)
+    for name in type(h1)._fields:
+        a, b = getattr(h1, name), getattr(h2, name)
+        assert jax.tree_util.tree_structure(a) == \
+            jax.tree_util.tree_structure(b), name
+        for x, y in zip(jax.tree_util.tree_leaves(a),
+                        jax.tree_util.tree_leaves(b)):
+            x, y = np.asarray(x), np.asarray(y)
+            assert x.dtype == y.dtype, name
+            np.testing.assert_array_equal(x, y, err_msg=name)
 
 
 def assert_close(actual, desired, bound: str, err_msg=""):

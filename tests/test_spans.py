@@ -2,8 +2,9 @@
 
 Each entry point runs once under a CPU profiler session. The spans are read
 back from the trace: their names, how they nest, one span per round or step
-numbered in order, the sync's count of reads back to the host, a log span
-only at logging steps. A traced run must compute what an untraced one does.
+numbered in order, the sync's count of arrays read back to the host in its
+one fetch, a log span only at logging steps. A traced run must compute what
+an untraced one does.
 
 The profiler is one per process, so every capture lives in this file.
 """
@@ -92,6 +93,8 @@ def mesh_traced(mesh_run):
 
 
 def test_run_mesh_spans_nest_by_phase(mesh_traced):
+    """Round k's span dispatches round k, then reads and accounts for
+    round k-1; the last round's span also drains its own."""
     _, spans = mesh_traced
     assert {s.name for s in spans} == MESH_SPANS
     call, = _named(spans, "run_mesh")
@@ -101,12 +104,14 @@ def test_run_mesh_spans_nest_by_phase(mesh_traced):
     rounds = _named(spans, "run_mesh/round")
     assert setup.end <= rounds[0].start
     assert rounds[-1].end <= history.start
-    for r in rounds:
+    for k, r in enumerate(rounds):
         inner = [s for s in spans if r.holds(s) and s is not r]
-        assert [s.name for s in inner] == [
-            "run_mesh/dispatch", "run_mesh/sync", "run_mesh/account"]
-        assert inner[0].end <= inner[1].start <= inner[1].end \
-            <= inner[2].start
+        reads = [k - 1] * (k > 0) + [k] * (k == ROUNDS - 1)
+        assert [s.name for s in inner] == ["run_mesh/dispatch"] + [
+            "run_mesh/sync", "run_mesh/account"] * len(reads)
+        assert [s.stats["round"] for s in inner
+                if s.name == "run_mesh/sync"] == reads
+        assert all(a.end <= b.start for a, b in zip(inner, inner[1:]))
 
 
 def test_run_mesh_one_round_span_per_round(mesh_traced):
@@ -114,6 +119,17 @@ def test_run_mesh_one_round_span_per_round(mesh_traced):
     rounds = _named(spans, "run_mesh/round")
     assert [r.stats["step_num"] for r in rounds] == list(range(ROUNDS))
     assert all(a.end <= b.start for a, b in zip(rounds, rounds[1:]))
+    for name in ("run_mesh/dispatch", "run_mesh/sync", "run_mesh/account"):
+        assert len(_named(spans, name)) == ROUNDS, name
+    assert [s.stats["round"] for s in _named(spans, "run_mesh/sync")] == \
+        list(range(ROUNDS))
+
+
+def _assert_one_fetch(syncs):
+    """Each sync blocks on one batched fetch, and says whether the round
+    it read was still running as it opened."""
+    assert [s.stats["fetches"] for s in syncs] == [1] * ROUNDS
+    assert all(s.stats["ahead"] in (0, 1) for s in syncs)
 
 
 @pytest.mark.parametrize("mask,metrics", [(True, False), (False, False),
@@ -124,16 +140,17 @@ def test_run_mesh_sync_counts_its_reads(mesh_run, mask, metrics):
                                            collect_metrics=metrics))
     bag = len(hist.metrics[0]) if metrics else 0    # the shard bag's keys
     # 7 server scalars, then per shard (K = 1) its slowest compute time,
-    # its mask rows and each entry of its metric bag
+    # its mask rows and each entry of its metric bag, in one fetch
     want = 7 + 1 + mask + bag
-    assert [s.stats["reads"] for s in _named(spans, "run_mesh/sync")] == \
-        [want] * ROUNDS
+    syncs = _named(spans, "run_mesh/sync")
+    assert [s.stats["reads"] for s in syncs] == [want] * ROUNDS
+    _assert_one_fetch(syncs)
 
 
 @pytest.mark.parametrize("shards", [2, 4])
 def test_run_mesh_sync_reads_two_arrays_per_shard(shards):
-    """7 + 2K reads at K > 1, in a child process that sees K CPU devices
-    (this one keeps its single-device view)."""
+    """7 + 2K arrays in one fetch at K > 1, in a child process that sees K
+    CPU devices (this one keeps its single-device view)."""
     code = textwrap.dedent(f"""
         import json, sys
         sys.path.insert(0, {TESTS!r})
@@ -146,7 +163,7 @@ def test_run_mesh_sync_reads_two_arrays_per_shard(shards):
         o = opt.make("chb", b.alpha_paper, 8)
         _, spans = _traced(lambda: run_mesh(
             o, b.task, {ROUNDS}, mesh=make_client_mesh({shards})))
-        print(json.dumps([s.stats["reads"]
+        print(json.dumps([s.stats
                           for s in _named(spans, "run_mesh/sync")]))
     """)
     env = {**os.environ, "PYTHONPATH": os.path.join(TESTS, "..", "src"),
@@ -154,8 +171,10 @@ def test_run_mesh_sync_reads_two_arrays_per_shard(shards):
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, env=env, timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]
-    assert json.loads(r.stdout.splitlines()[-1]) == \
-        [7 + 2 * shards] * ROUNDS
+    syncs = [Span("run_mesh/sync", 0, 0, st)
+             for st in json.loads(r.stdout.splitlines()[-1])]
+    assert [s.stats["reads"] for s in syncs] == [7 + 2 * shards] * ROUNDS
+    _assert_one_fetch(syncs)
 
 
 @pytest.mark.parametrize("route,want", [
